@@ -85,6 +85,8 @@ def validate_analytic_vs_compiled():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run("16x16")
     run("2x16x16")
     validate_analytic_vs_compiled()

@@ -58,11 +58,10 @@ def run():
 
     t_full = time_fn(gather_full_blocks, state)
     t_strip = time_fn(gather_strips, state)
-    from repro.utils.jax_compat import cost_analysis_dict
-    ca_full = cost_analysis_dict(
-        jax.jit(gather_full_blocks).lower(state).compile())
-    ca_strip = cost_analysis_dict(
-        jax.jit(gather_strips).lower(state).compile())
+    ca_full = jax.jit(gather_full_blocks).lower(state).compile(
+    ).cost_analysis() or {}
+    ca_strip = jax.jit(gather_strips).lower(state).compile(
+    ).cost_analysis() or {}
     b_full = ca_full.get("bytes accessed", 0.0)
     b_strip = ca_strip.get("bytes accessed", 0.0)
     emit("stencil_traffic/halo_assembly/full_blocks", t_full,
@@ -73,4 +72,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -32,15 +32,21 @@ from repro.workloads.base import MOORE_DIRS  # noqa: F401
 Array = jnp.ndarray
 
 
-def halo_regions(rho: int, k: int):
-    """The 8 (ys, xs) window slices of the depth-k halo frame, in
-    MOORE_DIRS order (NW, N, NE, W, E, SW, S, SE). Shared by the fused
-    kernels and the distributed engine to gate the periodic window mask
-    by per-block neighbor existence."""
+#: padded bytes of the depth-k windows one chunk of a halo-reading block
+#: update may span (``BlockLayout.map_chunks``): a TPU lays small
+#: trailing dims out in (8, 128) tiles, so the working set of a chunk is
+#: sized in those padded bytes, not in cells
+CHUNK_WINDOW_BYTES = 128 * 1024 * 1024
+
+
+def window_chunk(rho: int, k: int, n_channels: int = 1,
+                 multiple: int = 1) -> int:
+    """Blocks per ``map_chunks`` chunk for depth-``k`` windows: as many
+    as fit ``CHUNK_WINDOW_BYTES`` of (8, 128)-padded 32-bit window
+    planes, rounded down to a multiple of ``multiple``."""
     w = rho + 2 * k
-    lo, mid, hi = slice(0, k), slice(k, k + rho), slice(k + rho, w)
-    return ((lo, lo), (lo, mid), (lo, hi), (mid, lo), (mid, hi),
-            (hi, lo), (hi, mid), (hi, hi))
+    per = n_channels * (-(-w // 8) * 8) * (-(-w // 128) * 128) * 4
+    return max(multiple, CHUNK_WINDOW_BYTES // per // multiple * multiple)
 
 
 def compact_meshgrid(frac: NBBFractal, r: int) -> Tuple[Array, Array]:
@@ -94,16 +100,6 @@ class BlockLayout:
         _ = self.block_origin_expanded, self.neighbor_table
         _ = self.dev_micro_mask, self.dev_block_origin_expanded
         _ = self.dev_neighbor_table
-        return self
-
-    def materialize_halo(self, k: int) -> "BlockLayout":
-        """Build the depth-``k`` halo geometry eagerly (same contract as
-        ``materialize``: fused-k entry points call this outside any trace)."""
-        self.materialize()
-        _ = self.existence_table, self.dev_existence_table
-        _ = self.offset_table(k), self.window_mask(k), self.halo_mask(k)
-        _ = self.dev_offset_table(k), self.dev_window_mask(k)
-        _ = self.dev_halo_mask(k)
         return self
 
     @property
@@ -178,9 +174,8 @@ class BlockLayout:
     def existence_table(self) -> np.ndarray:
         """(n_blocks, 8) int32 {0,1}: 1 where the Moore neighbor block is a
         real fractal block, 0 where ``neighbor_table`` holds the ghost
-        sentinel. Scalar-prefetch operand of the fused-k kernel (gates the
-        periodic window mask so ghost halo regions stay zero across
-        substeps)."""
+        sentinel (the distributed engine's per-shard kernel operand; the
+        single-device paths derive it from the table on the device)."""
         return (self.neighbor_table != self.ghost).astype(np.int32)
 
     # --------------------------------------------- device-side cached tables
@@ -207,30 +202,17 @@ class BlockLayout:
         return self._to_device(self.micro_mask)
 
     @functools.cached_property
-    def dev_existence_table(self) -> Array:
-        """Device-side ``existence_table`` (one shared upload)."""
-        return self._to_device(self.existence_table)
-
-    @functools.cached_property
     def dev_block_origin_expanded(self) -> Array:
         """Device-side ``block_origin_expanded`` (one shared upload)."""
         return self._to_device(self.block_origin_expanded)
 
     def dev_offset_table(self, k: int) -> Array:
-        """Device-side ``offset_table(k)`` (one shared upload per depth)."""
+        """Device-side ``offset_table(k)`` (one shared upload per block
+        radius; radius 1 is ``dev_neighbor_table`` itself)."""
+        if self.halo_block_radius(k) == 1:
+            return self.dev_neighbor_table
         return self._memo(("dev_offset_table", self.halo_block_radius(k)),
                           lambda: self._to_device(self.offset_table(k)))
-
-    def dev_window_mask(self, k: int) -> Array:
-        """Device-side int32 ``window_mask(k)`` (shared upload per depth)."""
-        return self._memo(
-            ("dev_window_mask", k),
-            lambda: self._to_device(self.window_mask(k).astype(np.int32)))
-
-    def dev_halo_mask(self, k: int) -> Array:
-        """Device-side ``halo_mask(k)`` (one shared upload per depth)."""
-        return self._memo(("dev_halo_mask", k),
-                          lambda: self._to_device(self.halo_mask(k)))
 
     # ------------------------------------------------------- depth-k halos
     def halo_block_radius(self, k: int) -> int:
@@ -417,26 +399,6 @@ class BlockLayout:
         east = band(4, 2).swapaxes(-1, -2)   # E neighbor's west cols
         return top, bot, west, east
 
-    def existence_padded(self, k: int,
-                         p: Optional[int] = None) -> np.ndarray:
-        """(nb_pad, 8) int32 ``existence_table`` zero-padded to the macro
-        slot count: padding slots have no real neighbors, so their halo
-        regions stay ghost-gated to zero in the v5 kernel. ``p`` is the
-        macro-tile packing override (None = lane heuristic)."""
-        def build():
-            _, _, nb_pad = self.macro_tiles(k, p=p)
-            pad = np.zeros((nb_pad - self.n_blocks, 8), np.int32)
-            return np.concatenate([self.existence_table, pad], axis=0)
-        return self._memo(("existence_padded", k, p), build)
-
-    def dev_existence_padded(self, k: int,
-                             p: Optional[int] = None) -> Array:
-        """Device-side ``existence_padded(k)`` (shared upload per depth
-        and packing)."""
-        return self._memo(
-            ("dev_existence_padded", k, p),
-            lambda: self._to_device(self.existence_padded(k, p)))
-
     # ------------------------------------------ locality-aware sharding
     def strip_decomposition(self, n_shards: int) -> "StripDecomposition":
         """Locality-aware block->shard assignment for the neighbor-only
@@ -470,68 +432,152 @@ class BlockLayout:
         mask = self.dev_micro_mask
         return state_e[..., ay, ax] * mask.astype(state_e.dtype)
 
-    def pad_with_halo(self, state_b: Array) -> Array:
-        """Assemble (n_blocks, rho+2, rho+2) tiles with Moore halos.
+    def pad_with_halo(self, state_b: Array, table=None) -> Array:
+        """Assemble (n_blocks, rho+2, rho+2) tiles with Moore halos (the
+        depth-1 ``pad_with_halo_k``); ghost neighbors read as 0."""
+        return self.pad_with_halo_k(state_b, 1, table)
 
-        Gathers only the needed strips (edge rows/cols, corner cells) from
-        each neighbor block via the static table; ghost neighbors read as 0.
-        """
-        rho = self.rho
-        nb = self.n_blocks
-        # one zero ghost block appended: sentinel gathers read zeros.
-        padded_src = jnp.concatenate(
-            [state_b, jnp.zeros((1, rho, rho), state_b.dtype)], axis=0)
-        table = self.dev_neighbor_table  # (nb, 8)
+    def _window_grid(self, k: int, piece):
+        """Assemble a (..., rho+2k, rho+2k) window by concatenation from
+        ``piece(oi, window_rect, source_rect)`` — one call per block
+        offset of the depth-``k`` halo (``oi`` None for the center
+        tile). The offsets' overlaps tile the window in a (2kb+1)^2
+        grid, row band by row band."""
+        kb = self.halo_block_radius(k)
+        col = {o: i for i, o in enumerate(self.halo_offsets(k))}
+        rows = []
+        for bdy in range(-kb, kb + 1):
+            rows.append(jnp.concatenate(
+                [piece(col.get((bdx, bdy)),
+                       *self._halo_region(k, bdx, bdy))
+                 for bdx in range(-kb, kb + 1)], axis=-1))
+        return jnp.concatenate(rows, axis=-2)
 
-        out = jnp.zeros((nb, rho + 2, rho + 2), state_b.dtype)
-        out = out.at[:, 1:-1, 1:-1].set(state_b)
+    def halo_window_k(self, center: Array, nbr, k: int) -> Array:
+        """(..., n, rho+2k, rho+2k) depth-``k`` windows of ``center``
+        (..., n, rho, rho): ``nbr(oi)`` gives the (..., n, rho, rho)
+        neighbor blocks at ``halo_offsets(k)[oi]`` (ghosts all zero);
+        only each neighbor's overlap with the window is kept."""
+        def piece(oi, dst, src):
+            del dst
+            sy0, sy1, sx0, sx1 = src
+            blk = center if oi is None else nbr(oi)
+            return blk[..., sy0:sy1, sx0:sx1]
 
-        def nbr(d):  # (nb, rho, rho) neighbor-block contents for direction d
-            return jnp.take(padded_src, table[:, d], axis=0)
+        return self._window_grid(k, piece)
 
-        # MOORE_DIRS order: NW, N, NE, W, E, SW, S, SE
-        nw, n_, ne, w_, e_, sw, s_, se = (nbr(d) for d in range(8))
-        out = out.at[:, 0, 0].set(nw[:, -1, -1])
-        out = out.at[:, 0, 1:-1].set(n_[:, -1, :])
-        out = out.at[:, 0, -1].set(ne[:, -1, 0])
-        out = out.at[:, 1:-1, 0].set(w_[:, :, -1])
-        out = out.at[:, 1:-1, -1].set(e_[:, :, 0])
-        out = out.at[:, -1, 0].set(sw[:, 0, -1])
-        out = out.at[:, -1, 1:-1].set(s_[:, 0, :])
-        out = out.at[:, -1, -1].set(se[:, 0, 0])
-        return out
+    @staticmethod
+    def block_rows(flat: Array, idx: Array, n_blocks: int,
+                   rho: int) -> Array:
+        """Gather blocks ``idx`` (n,) from ``flat`` (..., n_blocks,
+        rho*rho), the lane-dense view of a block state: ids past the
+        layout (the ghost sentinel, padding rows) read as zero blocks.
+        Returns (..., n, rho, rho)."""
+        rows = jnp.take(flat, jnp.minimum(idx, n_blocks - 1), axis=-2)
+        rows = rows * (idx < n_blocks)[:, None].astype(rows.dtype)
+        return rows.reshape(rows.shape[:-1] + (rho, rho))
 
-    def pad_with_halo_k(self, state_b: Array, k: int) -> Array:
+    def map_chunks(self, state: Array, table: Array, chunk: int, fn):
+        """Apply a halo-reading block update chunk by chunk.
+
+        ``state`` (..., n_blocks, rho, rho); ``table`` (n_blocks, T)
+        neighbor ids per block (ghost = ``self.ghost``); ``fn(center,
+        nbr, rows)`` maps one chunk — ``center`` (..., c, rho, rho),
+        ``nbr(j)`` the blocks named by column ``j`` of the chunk's table
+        rows ``rows`` (c, T) — to its (..., c, rho, rho) update.
+
+        The chunks run in a ``fori_loop`` over a lane-dense (...,
+        n_blocks, rho*rho) view, so every temporary with small trailing
+        dims (halo strips, windows, which a TPU pads to (8, 128) tiles)
+        is chunk-sized: the working set stays bounded however many
+        blocks the layout has. One chunk when ``chunk >= n_blocks``."""
+        nb, rho = self.n_blocks, self.rho
+        lead = state.shape[:-3]
+        flat = state.reshape(lead + (nb, rho * rho))
+        if chunk >= nb:
+            return fn(state, lambda j: self.block_rows(
+                flat, table[:, j], nb, rho), table)
+        n_chunks = -(-nb // chunk)
+        table = jnp.concatenate(
+            [table, jnp.full((n_chunks * chunk - nb, table.shape[1]),
+                             self.ghost, table.dtype)], axis=0)
+
+        def body(c, out):
+            base = c * chunk
+            rows = jax.lax.dynamic_slice_in_dim(table, base, chunk, 0)
+            ids = base + jnp.arange(chunk, dtype=table.dtype)
+            res = fn(self.block_rows(flat, ids, nb, rho),
+                     lambda j: self.block_rows(flat, rows[:, j], nb, rho),
+                     rows)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, res.reshape(lead + (chunk, rho * rho)), base,
+                axis=out.ndim - 2)
+
+        out = jnp.zeros(lead + (n_chunks * chunk, rho * rho), state.dtype)
+        out = jax.lax.fori_loop(0, n_chunks, body, out)
+        return out[..., :nb, :].reshape(state.shape)
+
+    def pad_with_halo_k(self, state_b: Array, k: int, table=None) -> Array:
         """Assemble (n_blocks, rho+2k, rho+2k) tiles with depth-``k`` halos.
 
-        The depth-1 generalization of ``pad_with_halo``: for each block
-        offset in ``halo_offsets(k)`` only the overlap strip of the neighbor
-        tile with the window is sliced *before* the gather (so HBM traffic
-        stays ~perimeter * k, not offsets * rho^2); ghost ids index the
-        appended zero strip, which keeps out-of-fractal reads zero at every
-        depth.
+        For each block offset in ``halo_offsets(k)`` the neighbor tile
+        is gathered through the offset table and its overlap with the
+        window kept; ghost ids read as zero, which keeps out-of-fractal
+        reads zero at every depth. ``table`` is ``offset_table(k)`` as a
+        traced operand (default: the layout's device copy).
         """
         if k < 1:
             raise ValueError(f"halo depth must be >= 1, got {k}")
-        rho, nb = self.rho, self.n_blocks
-        w = rho + 2 * k
-        table = self.dev_offset_table(k)
-        out = jnp.zeros((nb, w, w), state_b.dtype)
-        out = out.at[:, k:k + rho, k:k + rho].set(state_b)
-        for oi, (bdx, bdy) in enumerate(self.halo_offsets(k)):
-            (dy0, dy1, dx0, dx1), (sy0, sy1, sx0, sx1) = \
-                self._halo_region(k, bdx, bdy)
-            strip = state_b[:, sy0:sy1, sx0:sx1]
-            strip = jnp.concatenate(
-                [strip, jnp.zeros((1,) + strip.shape[1:], state_b.dtype)],
-                axis=0)
-            out = out.at[:, dy0:dy1, dx0:dx1].set(
-                jnp.take(strip, table[:, oi], axis=0))
-        return out
+        if table is None:
+            table = self.dev_offset_table(k)
+        nb, rho = self.n_blocks, self.rho
+        flat = state_b.reshape(state_b.shape[:-3] + (nb, rho * rho))
+        return self.halo_window_k(
+            state_b, lambda oi: self.block_rows(flat, table[:, oi], nb, rho),
+            k)
+
+    def halo_gate(self, k: int, table) -> Array:
+        """(n, rho+2k, rho+2k) uint8 occupancy of each block's depth-``k``
+        window — ``halo_mask(k)`` rebuilt in-trace from the offset table
+        (``table`` (n, len(halo_offsets(k))), ghost = ``self.ghost``):
+        the periodic ``window_mask`` with each ghost neighbor's region
+        zeroed. Built on the device, so no (n_blocks, w, w) table is
+        ever uploaded or baked into a compiled program."""
+        exist = (table != self.ghost).astype(jnp.uint8)
+        n = table.shape[0]
+
+        def piece(oi, dst, src):
+            dy0, dy1, dx0, dx1 = dst
+            shape = (n, dy1 - dy0, dx1 - dx0)
+            if oi is None:
+                return jnp.ones(shape, jnp.uint8)
+            return jnp.broadcast_to(exist[:, oi, None, None], shape)
+
+        return self._window_grid(k, piece) * jnp.asarray(self.window_mask(k))
+
+    def init_state(self, workload, seed) -> Array:
+        """Seeded initial block state (C?, n_blocks, rho, rho), drawn on
+        the device in block space from ``workloads.base.cell_bits`` at
+        every cell's expanded coordinates — the same field the expanded
+        baselines draw over the (n, n) embedding, with holes zeroed."""
+        return _init_blocks(self, workload, jnp.asarray(seed, jnp.int32),
+                            self.dev_block_origin_expanded)
 
     def memory_bytes(self, dtype_size: int = 1) -> int:
         """Squeeze block-level state bytes (paper Table 2's nu column)."""
         return self.n_blocks * self.rho * self.rho * dtype_size
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init_blocks(layout: "BlockLayout", workload, seed, org) -> Array:
+    from repro.workloads.base import cell_bits
+    rho = layout.rho
+    iy, ix = jnp.meshgrid(jnp.arange(rho, dtype=jnp.int32),
+                          jnp.arange(rho, dtype=jnp.int32), indexing="ij")
+    bits = cell_bits(seed, org[:, 0, None, None] + ix[None],
+                     org[:, 1, None, None] + iy[None])
+    field = workload.init(bits)
+    return field * jnp.asarray(layout.micro_mask).astype(field.dtype)
 
 
 def _balanced_contiguous_partition(counts: np.ndarray,
@@ -644,19 +690,22 @@ class StripDecomposition:
                       shard_of=None, local_of=None)
             return
         bounds = _balanced_contiguous_partition(counts, self.n_shards)
-        row_shard = {int(rows[i]): s
-                     for s, (a, b) in enumerate(bounds)
-                     for i in range(a, b)}
-        shard_of = np.array([row_shard[int(y)] for y in ey], np.int32)
+        # vectorized over blocks: the builds stay O(nb log nb) numpy at
+        # paper scale (nb = 3^13 at Sierpinski r=17, m=4)
+        row_shard = np.empty(len(rows), np.int32)
+        for sh, (a, b) in enumerate(bounds):
+            row_shard[a:b] = sh
+        shard_of = row_shard[np.searchsorted(rows, ey)].astype(np.int32)
         nbl = int(max(counts[a:b].sum() for a, b in bounds))
-        perm = np.full(self.n_shards * nbl, -1, np.int32)
+        # row-major within each strip: rank of each block in its shard
+        ordered_shards = shard_of[order]
+        starts = np.searchsorted(ordered_shards,
+                                 np.arange(self.n_shards))
         local_of = np.empty(nb, np.int32)
-        fill = np.zeros(self.n_shards, np.int32)
-        for g in order:  # row-major within each strip
-            s = shard_of[g]
-            local_of[g] = fill[s]
-            perm[s * nbl + fill[s]] = g
-            fill[s] += 1
+        local_of[order] = (np.arange(nb) - starts[ordered_shards]
+                           ).astype(np.int32)
+        perm = np.full(self.n_shards * nbl, -1, np.int32)
+        perm[shard_of * nbl + local_of] = np.arange(nb, dtype=np.int32)
         self._set(valid=True, nb_local=nbl,
                   nb_padded=self.n_shards * nbl, perm=perm,
                   shard_of=shard_of, local_of=local_of)
@@ -667,69 +716,63 @@ class StripDecomposition:
         table_g = layout.neighbor_table  # (nb, 8) compact block ids
         ghost = layout.ghost
         shard_of, local_of = self.shard_of, self.local_of
-        send_prev = [[] for _ in range(ns)]  # local idx needed by s-1
-        send_next = [[] for _ in range(ns)]  # local idx needed by s+1
-        for g in range(layout.n_blocks):
-            s = int(shard_of[g])
-            for ng in table_g[g]:
-                if ng == ghost:
-                    continue
-                d = int(shard_of[ng]) - s
-                if abs(d) > 1:  # the row-strip invariant
-                    raise AssertionError(
-                        f"strip decomposition broke: blocks {g}->{ng} "
-                        f"span shards {s}->{shard_of[ng]}")
-                # ng's strip travels from its shard s+d back to s, i.e.
-                # shard s+1 sends to its PREV neighbor and vice versa
-                tgt = send_prev if d == 1 else send_next if d == -1 \
-                    else None
-                if tgt is not None and local_of[ng] not in tgt[s + d]:
-                    tgt[s + d].append(int(local_of[ng]))
-        for lst in (*send_prev, *send_next):
-            lst.sort()
+        g, d = np.nonzero(table_g != ghost)  # every real (block, dir)
+        ng = table_g[g, d]
+        s, so = shard_of[g], shard_of[ng]
+        delta = so - s
+        bad = np.flatnonzero(np.abs(delta) > 1)
+        if bad.size:  # the row-strip invariant
+            i = bad[0]
+            raise AssertionError(
+                f"strip decomposition broke: blocks {g[i]}->{ng[i]} "
+                f"span shards {s[i]}->{so[i]}")
+        # ng's strip travels from its shard so back to s, i.e. shard s+1
+        # sends to its PREV neighbor and shard s-1 to its NEXT one
+        lo = local_of[ng]
+
+        def needed(sign):
+            sel = delta == sign
+            return [np.unique(lo[sel & (so == sh)]) for sh in range(ns)]
+
+        send_prev, send_next = needed(1), needed(-1)
         # >= 1 slot: the ppermute operands must never be zero-sized
         ms_prev = max(1, max(len(x) for x in send_prev))
         ms_next = max(1, max(len(x) for x in send_next))
 
         def pad(lists, width):
             out = np.full((ns, width), nbl, np.int32)  # zero-strip row
-            for s, lst in enumerate(lists):
-                out[s, :len(lst)] = lst
+            for sh, lst in enumerate(lists):
+                out[sh, :len(lst)] = lst
             return out
 
-        slot_prev = [{li: j for j, li in enumerate(lst)}
-                     for lst in send_prev]
-        slot_next = [{li: j for j, li in enumerate(lst)}
-                     for lst in send_next]
+        def slot(lists, shards, ids):
+            """Position of each local id in its shard's send list."""
+            out = np.zeros(len(ids), np.int64)
+            for sh in range(ns):
+                sel = shards == sh
+                out[sel] = np.searchsorted(lists[sh], ids[sel])
+            return out
 
         # per-shard halo table in combined strip coordinates
         table = np.full((ns, nbl, 8), nbl, np.int32)
+        li = local_of[g]
+        same, from_prev = delta == 0, delta == -1
+        from_next = delta == 1
+        table[s[same], li[same], d[same]] = lo[same]
+        # recv-from-prev slab = prev shard's send_next buffer (width
+        # ms_next); recv-from-next slab = next shard's send_prev buffer
+        table[s[from_prev], li[from_prev], d[from_prev]] = (
+            nbl + 1 + slot(send_next, so[from_prev], lo[from_prev]))
+        table[s[from_next], li[from_next], d[from_next]] = (
+            nbl + 1 + ms_next + slot(send_prev, so[from_next],
+                                     lo[from_next]))
         remote = np.zeros((ns, nbl), bool)
-        for g in range(layout.n_blocks):
-            s, li = int(shard_of[g]), int(local_of[g])
-            for d in range(8):
-                ng = table_g[g, d]
-                if ng == ghost:
-                    continue  # stays the zero ghost row
-                so, lo = int(shard_of[ng]), int(local_of[ng])
-                if so == s:
-                    table[s, li, d] = lo
-                elif so == s - 1:
-                    # recv-from-prev slab = prev shard's send_next
-                    # buffer (width ms_next)
-                    table[s, li, d] = nbl + 1 + slot_next[so][lo]
-                    remote[s, li] = True
-                else:
-                    # recv-from-next slab = next shard's send_prev
-                    # buffer (width ms_prev)
-                    table[s, li, d] = (nbl + 1 + ms_next
-                                       + slot_prev[so][lo])
-                    remote[s, li] = True
+        remote[s[~same], li[~same]] = True
 
         # interior/boundary partition of every local slot (dead padding
         # slots are interior: they compute to zero without any strip)
-        interior = [np.flatnonzero(~remote[s]) for s in range(ns)]
-        boundary = [np.flatnonzero(remote[s]) for s in range(ns)]
+        interior = [np.flatnonzero(~remote[sh]) for sh in range(ns)]
+        boundary = [np.flatnonzero(remote[sh]) for sh in range(ns)]
         mi = max(1, max(len(x) for x in interior))
         mb = max(1, max(len(x) for x in boundary))
         self._set(
@@ -741,9 +784,9 @@ class StripDecomposition:
             boundary_idx=pad(boundary, mb),
             n_interior=np.array([len(x) for x in interior], np.int32),
             n_boundary=np.array([len(x) for x in boundary], np.int32),
-            real_sends=sum(1 for s in range(ns - 1)
-                           if len(send_next[s])) +
-            sum(1 for s in range(1, ns) if len(send_prev[s])),
+            real_sends=sum(1 for sh in range(ns - 1)
+                           if len(send_next[sh])) +
+            sum(1 for sh in range(1, ns) if len(send_prev[sh])),
         )
 
     # ------------------------------------------------------- exchange ops

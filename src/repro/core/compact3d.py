@@ -154,8 +154,8 @@ class BlockLayout3D:
     @functools.cached_property
     def existence_table(self) -> np.ndarray:
         """(n_blocks, 26) int32 {0,1}: 1 where the Moore neighbor block
-        is a real fractal block (scalar-prefetch operand of the fused 3D
-        kernels, gating the periodic window mask per substep)."""
+        is a real fractal block (the fused 3D kernels' per-block rows,
+        gating the periodic window mask per substep)."""
         return (self.neighbor_table != self.ghost).astype(np.int32)
 
     # --------------------------------------------- device-side cached tables

@@ -451,8 +451,8 @@ class DistributedSqueezeEngine:
             the exact-past-holes Moore table), ghosts pre-remapped to the
             appended zero-strip row — gather: global strip ids; p2p: the
             decomposition's combined per-shard strip coordinates;
-          * existence (nb_padded, 8) int32: scalar-prefetch operand of the
-            shard-local kernels' in-kernel mask reconstruction.
+          * existence (nb_padded, 8) int32: the shard-local kernels'
+            per-block rows for the in-kernel mask reconstruction.
         """
         def build_gather():
             layout = self.layout
@@ -530,23 +530,8 @@ class DistributedSqueezeEngine:
     def _materialize(self, k: int) -> None:
         """Build every static host/device table a depth-``k`` traced step
         reads — outside any trace."""
-        layout = self.layout
-        layout.materialize()
+        self.layout.materialize()
         _ = self._shard_operands(k)
-        if self.compute != "jnp":
-            _ = layout.dev_window_mask(k)
-        if self.compute == "mxu":
-            from repro.kernels.squeeze_stencil import _mxu_operators
-            if self.exchange_mode == "p2p":
-                # full-domain overlap pass + boundary patch pass
-                sizes = {self.nb_local,
-                         self.decomp.boundary_idx.shape[1]}
-            else:
-                sizes = {self.nb_local}
-            for n_sel in sizes:
-                p_local = layout.macro_tiles_for(n_sel, k,
-                                                 p=self.macro_p)[0]
-                _mxu_operators(self.workload, layout.rho + 2 * k, p_local)
 
     # ---------------------------------------------------- shard-local compute
     def _compute_k(self, states: Array, halo, mask: Array,
@@ -714,7 +699,6 @@ class DistributedSqueezeEngine:
         sharded static operands."""
         def build():
             self._materialize(k)
-            from repro.utils.jax_compat import shard_map
             spec = self.state_spec(5)
             row = P(self.axis, None)
             if self.exchange_mode == "p2p":
@@ -726,9 +710,9 @@ class DistributedSqueezeEngine:
                 in_specs = (spec, P(self.axis, None, None), row, row)
             # pallas_call has no shard_map replication rule: the kernel
             # computes must disable the (conservative) rep check
-            step = shard_map(
+            step = jax.shard_map(
                 local, mesh=self.mesh, in_specs=in_specs,
-                out_specs=spec, check_rep=self.compute == "jnp")
+                out_specs=spec, check_vma=self.compute == "jnp")
             return jax.jit(step, donate_argnums=0) if donate \
                 else jax.jit(step)
         return self._memo(("step5", k, donate), build)

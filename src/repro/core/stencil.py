@@ -41,10 +41,11 @@ from repro.core import maps
 from repro.core.baselines import (BBEngine, _moore_counts,  # noqa: F401
                                   life_rule)
 from repro.core.compact import (BlockLayout, MOORE_DIRS, compact_meshgrid,
-                                compact_to_expanded, expanded_to_compact)
+                                compact_to_expanded, window_chunk)
 from repro.core.fractals import NBBFractal
-from repro.workloads.base import (StencilWorkload, check_workload_ndim,
-                                  weighted_gather_agg, weighted_moore_agg)
+from repro.workloads.base import (StencilWorkload, cell_bits,
+                                  check_workload_ndim, weighted_gather_agg,
+                                  weighted_moore_agg)
 from repro.workloads.rules import LIFE
 
 Array = jnp.ndarray
@@ -67,10 +68,17 @@ def default_fusion_k(rho: int) -> int:
 
 
 class _CachedRun:
-    """Cached-jit run machinery: hosts define ``_run_impl(state, steps)``
-    with a *traced* steps scalar, and their ``run`` dispatches through
-    ``_dispatch_run`` — one plain and one ``donate_argnums`` compilation
-    per engine value, neither retracing when the step count changes.
+    """Cached-jit run machinery: hosts define ``_run_impl(state, steps,
+    ops)`` with a *traced* steps scalar, and their ``run`` dispatches
+    through ``_dispatch_run`` — one plain and one ``donate_argnums``
+    compilation per engine value, neither retracing when the step count
+    changes.
+
+    ``operands()`` is the pytree of device tables the traced step reads
+    (the block-neighbor table, ...). It is passed into every compiled
+    program as an argument: a table closed over by ``jit`` would be
+    baked into the executable as a constant (51 MB of neighbor table at
+    r=17), so the steps take it as ``ops`` instead.
 
     The ``engine.trace`` counter increments only while jax traces the
     body (cached dispatches skip it), so the telemetry registry turns
@@ -78,28 +86,33 @@ class _CachedRun:
     invariant (see tests/test_obs.py; counts appear only if telemetry
     was enabled at trace time)."""
 
+    def operands(self):
+        """Device tables the traced steps read (none by default)."""
+        return {}
+
     @partial(jax.jit, static_argnums=0)
-    def _run(self, state: Array, steps) -> Array:
+    def _run(self, state: Array, steps, ops) -> Array:
         obs.inc("engine.trace", engine=type(self).__name__, fn="run")
-        return self._run_impl(state, steps)
+        return self._run_impl(state, steps, ops)
 
     @partial(jax.jit, static_argnums=0, donate_argnums=1)
-    def _run_donated(self, state: Array, steps) -> Array:
+    def _run_donated(self, state: Array, steps, ops) -> Array:
         obs.inc("engine.trace", engine=type(self).__name__,
                 fn="run_donated")
-        return self._run_impl(state, steps)
+        return self._run_impl(state, steps, ops)
 
     def _dispatch_run(self, state: Array, steps, donate: bool) -> Array:
         fn = self._run_donated if donate else self._run
-        return fn(state, jnp.asarray(steps, jnp.int32))
+        return fn(state, jnp.asarray(steps, jnp.int32), self.operands())
 
 
 class _FusedStepping(_CachedRun):
     """Temporal-fusion run machinery shared by the block engines.
 
-    Hosts require a ``layout``, a ``fusion_k`` field, ``step(state)`` and
-    ``step_k(state, k)``; they override ``_materialize_fused(k)`` to build
-    whatever static geometry their k-step body reads (outside any trace).
+    Hosts require a ``layout``, a ``fusion_k`` field, ``step(state,
+    ops=None)`` and ``step_k(state, k, ops=None)``; hosts whose k-step
+    body reads host-built geometry outside ``operands()`` override
+    ``_materialize_fused(k)`` to build it outside any trace.
     """
 
     @property
@@ -109,17 +122,18 @@ class _FusedStepping(_CachedRun):
         return default_fusion_k(self.layout.rho)
 
     def _materialize_fused(self, k: int) -> None:
-        raise NotImplementedError
+        pass
 
-    def _run_impl(self, state: Array, steps) -> Array:
+    def _run_impl(self, state: Array, steps, ops) -> Array:
         k = self.effective_fusion_k
         if k <= 1:
             return jax.lax.fori_loop(0, steps,
-                                     lambda _, s: self.step(s), state)
+                                     lambda _, s: self.step(s, ops), state)
         state = jax.lax.fori_loop(0, steps // k,
-                                  lambda _, s: self.step_k(s, k), state)
+                                  lambda _, s: self.step_k(s, k, ops),
+                                  state)
         return jax.lax.fori_loop(0, steps % k,
-                                 lambda _, s: self.step(s), state)
+                                 lambda _, s: self.step(s, ops), state)
 
     def run(self, state: Array, steps, donate: bool = False) -> Array:
         """``steps`` steps, tiled into floor(steps/k) fused k-step launches
@@ -164,9 +178,9 @@ class SqueezeCellEngine(_CachedRun):
         check_workload_ndim(self.workload, 2)
 
     def init_random(self, seed: int) -> Array:
-        expanded = BBEngine(self.frac, self.r,
-                            self.workload).init_random(seed)
-        return expanded_to_compact(self.frac, self.r, expanded)
+        cx, cy = compact_meshgrid(self.frac, self.r)
+        ex, ey = maps.lambda_map(self.frac, self.r, cx, cy)
+        return self.workload.init(cell_bits(seed, ex, ey))
 
     def to_expanded(self, state: Array) -> Array:
         return compact_to_expanded(self.frac, self.r, state)
@@ -190,7 +204,7 @@ class SqueezeCellEngine(_CachedRun):
         # every compact cell is a fractal cell: no mask
         return wl.apply(state, agg, None).astype(state.dtype)
 
-    def _run_impl(self, state: Array, steps) -> Array:
+    def _run_impl(self, state: Array, steps, ops) -> Array:
         return jax.lax.fori_loop(0, steps, lambda _, s: self.step(s), state)
 
     def run(self, state: Array, steps, donate: bool = False) -> Array:
@@ -237,44 +251,68 @@ class SqueezeBlockEngine(_FusedStepping):
         return self.layout.r
 
     def init_random(self, seed: int) -> Array:
-        expanded = BBEngine(self.frac, self.r,
-                            self.workload).init_random(seed)
-        return self.layout.from_expanded(expanded)
+        return self.layout.init_state(self.workload, seed)
 
     def to_expanded(self, state: Array) -> Array:
         return self.layout.to_expanded(state)
 
+    def operands(self):
+        """The neighbor table (and, for fusion deeper than one block
+        ring, the depth-k offset table) as traced step operands."""
+        ops = {"table": self.layout.dev_neighbor_table}
+        k = self.effective_fusion_k
+        if self.layout.halo_block_radius(k) > 1:
+            ops["table_k"] = self.layout.dev_offset_table(k)
+        return ops
+
+    def _table_k(self, k: int, ops) -> Array:
+        if ops is None:
+            return self.layout.dev_offset_table(k)
+        if self.layout.halo_block_radius(k) == 1:
+            return ops["table"]
+        if "table_k" in ops and ops["table_k"].shape[1] == len(
+                self.layout.halo_offsets(k)):
+            return ops["table_k"]
+        return self.layout.dev_offset_table(k)
+
+    def step(self, state: Array, ops=None) -> Array:
+        return self._step(state, self._table_k(1, ops))
+
     @partial(jax.jit, static_argnums=0)
-    def step(self, state: Array) -> Array:
-        wl = self.workload
-        pad = self.layout.pad_with_halo
-        if wl.n_channels > 1:
-            pad = jax.vmap(pad)  # over the leading channel axis
-        padded = pad(state)  # (C?, nb, rho+2, rho+2)
-        agg = weighted_moore_agg(padded, wl.weights2d, wl.agg_dtype)
-        mask = self.layout.dev_micro_mask  # broadcasts over C?, nb
-        return wl.apply(state, agg, mask).astype(state.dtype)
+    def _step(self, state: Array, table: Array) -> Array:
+        wl, layout = self.workload, self.layout
+        mask = jnp.asarray(layout.micro_mask)
+
+        def update(center, nbr, rows):
+            padded = layout.halo_window_k(center, nbr, 1)
+            agg = weighted_moore_agg(padded, wl.weights2d, wl.agg_dtype)
+            return wl.apply(center, agg, mask).astype(center.dtype)
+
+        return layout.map_chunks(state, table, self._chunk(1), update)
+
+    def _chunk(self, k: int) -> int:
+        return window_chunk(self.layout.rho, k, self.workload.n_channels)
 
     # ------------------------------------------------------ temporal fusion
-    def _materialize_fused(self, k: int) -> None:
-        self.layout.materialize_halo(k)
-
-    def step_k(self, state: Array, k: int) -> Array:
+    def step_k(self, state: Array, k: int, ops=None) -> Array:
         """Advance ``k`` exact steps in one fused computation: one depth-k
         halo assembly, then k in-register substeps on the shrinking window
-        (XLA path; any k >= 1, including k > rho)."""
-        self.layout.materialize_halo(k)  # host tables outside the trace
-        return self._step_k(state, k)
+        (XLA path; any k >= 1, including k > rho). The per-block window
+        occupancy is rebuilt from the offset table on the device
+        (``BlockLayout.halo_gate``); blocks are updated in bounded chunks
+        (``BlockLayout.map_chunks``)."""
+        return self._step_k(state, k, self._table_k(k, ops))
 
     @partial(jax.jit, static_argnums=(0, 2))
-    def _step_k(self, state: Array, k: int) -> Array:
-        wl = self.workload
-        pad = partial(self.layout.pad_with_halo_k, k=k)
-        if wl.n_channels > 1:
-            pad = jax.vmap(pad)  # over the leading channel axis
-        padded = pad(state)  # (C?, nb, rho+2k, rho+2k)
-        hmask = self.layout.dev_halo_mask(k)  # (nb, rho+2k, rho+2k)
-        return wl.tile_rule_k(padded, hmask, k).astype(state.dtype)
+    def _step_k(self, state: Array, k: int, table: Array) -> Array:
+        wl, layout = self.workload, self.layout
+
+        def update(center, nbr, rows):
+            padded = layout.halo_window_k(center, nbr, k)
+            hmask = layout.halo_gate(k, rows)  # (c, rho+2k, rho+2k)
+            return wl.tile_rule_k(padded, hmask, k).astype(center.dtype)
+
+        return layout.map_chunks(state, table, self._chunk(k), update)
 
     def memory_bytes(self, dtype_size: int = 1) -> int:
         return self.workload.n_channels * self.layout.memory_bytes(dtype_size)
@@ -286,17 +324,18 @@ class SqueezePallasEngine(_FusedStepping):
 
     ``variant`` selects the halo strategy of kernels/squeeze_stencil.py:
     'blocks' (v1, paper-shaped), 'strips' (v2, pre-gathered strip halos),
-    'fused' (v3, in-kernel strip reads) or 'mxu' (v5, stencil-as-matmul on
-    lane-packed macro-tiles). State layout and conversions are identical
-    to ``SqueezeBlockEngine``. ``run`` steps through the temporal-fusion
-    kernel (v4 ``stencil_step_fused_k``, or the v5 k-substep variant for
-    'mxu') whenever the effective fusion depth is > 1; ``fusion_k``
-    overrides the heuristic but must stay <= rho (the kernels'
-    one-block-ring limit).
+    'fused' (the v4 depth-k kernel, one substep for single steps) or
+    'mxu' (v5, stencil-as-matmul on lane-packed macro-tiles). State
+    layout and conversions are identical to ``SqueezeBlockEngine``.
+    ``run`` steps through the temporal-fusion kernel (v4
+    ``stencil_step_fused_k``, or the v5 k-substep variant for 'mxu')
+    whenever the effective fusion depth is > 1; ``fusion_k`` overrides
+    the heuristic but must stay <= rho (the kernels' one-block-ring
+    limit).
 
     The 'mxu' variant additionally supports *native batching*
     (``step_batched`` / ``step_k_batched``): B independent simulations
-    advance through ONE kernel dispatch over a (B, n_macro_tiles) grid
+    advance through ONE kernel dispatch over a (B, macro-tile) grid
     instead of a vmap of per-simulation pallas_calls — the
     ``BatchedRunner`` routes through it when ``supports_native_batch``.
     """
@@ -333,66 +372,69 @@ class SqueezePallasEngine(_FusedStepping):
         return self.layout.r
 
     def init_random(self, seed: int) -> Array:
-        return SqueezeBlockEngine(self.layout,
-                                  self.workload).init_random(seed)
+        return self.layout.init_state(self.workload, seed)
 
     def to_expanded(self, state: Array) -> Array:
         return self.layout.to_expanded(state)
 
-    def step(self, state: Array) -> Array:
-        from repro.kernels import ops
+    def operands(self):
+        """The neighbor table, a traced operand of every kernel launch."""
+        return {"table": self.layout.dev_neighbor_table}
+
+    @staticmethod
+    def _table(ops):
+        return None if ops is None else ops["table"]
+
+    def step(self, state: Array, ops=None) -> Array:
+        from repro.kernels import ops as kops
+        table = self._table(ops)
         if self.variant == "mxu":
-            return ops.stencil_step_mxu(self.layout, state, self.workload,
-                                        p=self.macro_p)
-        fn = {"blocks": ops.stencil_step_blocks,
-              "strips": ops.stencil_step_strips,
-              "fused": ops.stencil_step_fused}[self.variant]
-        return fn(self.layout, state, self.workload)
+            return kops.stencil_step_mxu(self.layout, state, self.workload,
+                                         p=self.macro_p, table=table)
+        if self.variant == "fused":
+            return kops.stencil_step_fused_k(self.layout, state,
+                                             self.workload, k=1,
+                                             table=table)
+        fn = {"blocks": kops.stencil_step_blocks,
+              "strips": kops.stencil_step_strips}[self.variant]
+        return fn(self.layout, state, self.workload, table=table)
 
     # ------------------------------------------------------- native batching
     @property
     def supports_native_batch(self) -> bool:
-        """True when B simulations step through one (B, n_macro) kernel
-        grid rather than a vmap of per-simulation pallas_calls."""
+        """True when B simulations step through one (B, macro-tile)
+        kernel grid rather than a vmap of per-simulation pallas_calls."""
         return self.variant == "mxu"
 
-    def step_batched(self, states: Array) -> Array:
+    def step_batched(self, states: Array, ops=None) -> Array:
         """One step of B independent simulations in one kernel dispatch;
         states (B, C?, n_blocks, rho, rho) -> same ('mxu' variant only)."""
-        return self.step_k_batched(states, 1)
+        return self.step_k_batched(states, 1, ops)
 
-    def step_k_batched(self, states: Array, k: int) -> Array:
+    def step_k_batched(self, states: Array, k: int, ops=None) -> Array:
         """``k`` exact steps of B independent simulations in one kernel
-        dispatch over the (B, n_macro_tiles) grid ('mxu' variant only)."""
+        dispatch over the (B, macro-tile) grid ('mxu' variant only)."""
         if not self.supports_native_batch:
             raise ValueError(
                 f"native batching needs variant='mxu', got {self.variant!r} "
                 "(use jax.vmap over step/step_k instead)")
-        from repro.kernels import ops
-        return ops.stencil_step_mxu_batched(self.layout, states,
-                                            self.workload, k=k,
-                                            p=self.macro_p)
+        from repro.kernels import ops as kops
+        return kops.stencil_step_mxu_batched(self.layout, states,
+                                             self.workload, k=k,
+                                             p=self.macro_p,
+                                             table=self._table(ops))
 
     # ------------------------------------------------------ temporal fusion
-    def _materialize_fused(self, k: int) -> None:
-        # only what the fused kernels read — not the XLA path's per-block
-        # halo_mask/offset_table (O(n_blocks (rho+2k)^2) host build)
-        _ = self.layout.dev_existence_table, self.layout.dev_window_mask(k)
-        if self.variant == "mxu":
-            # resolve the packing override to its concrete P — the same
-            # memo key the kernel wrapper uses
-            p = self.layout.macro_tiles(k, p=self.macro_p)[0]
-            _ = self.layout.dev_existence_padded(k, p=p)
-
-    def step_k(self, state: Array, k: int) -> Array:
+    def step_k(self, state: Array, k: int, ops=None) -> Array:
         """Advance ``k`` exact steps in one fused kernel launch (k <= rho):
         the v5 macro-tile kernel for 'mxu', the v4 kernel otherwise."""
-        from repro.kernels import ops
+        from repro.kernels import ops as kops
+        table = self._table(ops)
         if self.variant == "mxu":
-            return ops.stencil_step_mxu_k(self.layout, state, self.workload,
-                                          k=k, p=self.macro_p)
-        return ops.stencil_step_fused_k(self.layout, state, self.workload,
-                                        k=k)
+            return kops.stencil_step_mxu_k(self.layout, state, self.workload,
+                                           k=k, p=self.macro_p, table=table)
+        return kops.stencil_step_fused_k(self.layout, state, self.workload,
+                                         k=k, table=table)
 
     def memory_bytes(self, dtype_size: int = 1) -> int:
         return self.workload.n_channels * self.layout.memory_bytes(dtype_size)

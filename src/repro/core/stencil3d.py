@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro.core import fractals3d as f3
 from repro.core.compact3d import BlockLayout3D
 from repro.core.stencil import _CachedRun, _FusedStepping
-from repro.workloads.base import (MOORE3_DIRS, StencilWorkload,
+from repro.workloads.base import (MOORE3_DIRS, StencilWorkload, cell_bits,
                                   check_workload_ndim, weighted_gather_agg,
                                   weighted_moore_agg3)
 from repro.workloads.rules import LIFE3D
@@ -77,7 +77,9 @@ class BB3DEngine(_CachedRun):
     def init_random(self, seed: int) -> Array:
         n = self.frac.side(self.r)
         mask = jnp.asarray(self.frac.mask(self.r))
-        field = self.workload.init(jax.random.PRNGKey(seed), (n, n, n))
+        ez, ey, ex = jnp.meshgrid(*(jnp.arange(n, dtype=jnp.int32),) * 3,
+                                  indexing="ij")
+        field = self.workload.init(cell_bits(seed, ex, ey, ez))
         return field * mask.astype(field.dtype)
 
     @partial(jax.jit, static_argnums=0)
@@ -88,7 +90,7 @@ class BB3DEngine(_CachedRun):
         agg = weighted_moore_agg3(padded, wl.weights3d, wl.agg_dtype)
         return wl.apply(state, agg, mask).astype(state.dtype)
 
-    def _run_impl(self, state: Array, steps) -> Array:
+    def _run_impl(self, state: Array, steps, ops) -> Array:
         return jax.lax.fori_loop(0, steps, lambda _, s: self.step(s), state)
 
     def run(self, state: Array, steps, donate: bool = False) -> Array:
@@ -148,7 +150,7 @@ class Squeeze3DEngine(_CachedRun):
                                   state.shape, wl.agg_dtype)
         return wl.apply(state, agg, None).astype(state.dtype)
 
-    def _run_impl(self, state: Array, steps) -> Array:
+    def _run_impl(self, state: Array, steps, ops) -> Array:
         return jax.lax.fori_loop(0, steps, lambda _, s: self.step(s), state)
 
     def run(self, state: Array, steps, donate: bool = False) -> Array:
@@ -201,8 +203,11 @@ class Squeeze3DBlockEngine(_FusedStepping):
     def to_expanded(self, state: Array) -> Array:
         return self.layout.to_expanded(state)
 
+    def step(self, state: Array, ops=None) -> Array:
+        return self._step(state)
+
     @partial(jax.jit, static_argnums=0)
-    def step(self, state: Array) -> Array:
+    def _step(self, state: Array) -> Array:
         wl = self.workload
         self.layout.materialize_halo(1)
         padded = self.layout.pad_with_halo_k(state, 1)
@@ -215,7 +220,7 @@ class Squeeze3DBlockEngine(_FusedStepping):
         self.layout.materialize_halo(k)
         self.layout.materialize_halo(1)  # the remainder path's step()
 
-    def step_k(self, state: Array, k: int) -> Array:
+    def step_k(self, state: Array, k: int, ops=None) -> Array:
         """Advance ``k`` exact steps in one fused computation: one
         depth-k halo assembly, then k in-register substeps on the
         shrinking window (XLA path; any k >= 1, including k > rho)."""
@@ -284,7 +289,7 @@ class Squeeze3DPallasEngine(_FusedStepping):
     def to_expanded(self, state: Array) -> Array:
         return self.layout.to_expanded(state)
 
-    def step(self, state: Array) -> Array:
+    def step(self, state: Array, ops=None) -> Array:
         return self.step_k(state, 1)
 
     # ------------------------------------------------------ temporal fusion
@@ -298,7 +303,7 @@ class Squeeze3DPallasEngine(_FusedStepping):
                 p = self.layout.macro_tiles(kk, p=self.macro_p)[0]
                 _ = self.layout.dev_existence_padded(kk, p=p)
 
-    def step_k(self, state: Array, k: int) -> Array:
+    def step_k(self, state: Array, k: int, ops=None) -> Array:
         """Advance ``k`` exact steps in one fused kernel launch
         (k <= rho)."""
         from repro.kernels import squeeze_stencil3d as k3

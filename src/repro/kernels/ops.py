@@ -1,11 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
 ``interpret=None`` everywhere means auto-detect: compiled Mosaic on a TPU
-runtime, the Pallas interpreter on every other backend (this container is
-CPU-only; the kernels are validated in interpret mode per the kernel
-tests). Pass an explicit bool to pin it. Resolution happens once, in the
-kernel entry points (``kernels.common.resolve_interpret``); these
-wrappers pass ``interpret`` through untouched.
+runtime, the Pallas interpreter on the CPU (the test suite's backend),
+an error on any other backend. Pass an explicit bool to pin it.
+Resolution happens once, in the kernel entry points
+(``kernels.common.resolve_interpret``); these wrappers pass
+``interpret`` through untouched.
 
 With telemetry enabled, every stencil entry point counts
 ``kernel.entry{op=...}`` on the registry. The wrapper body runs once
@@ -43,70 +43,72 @@ def lambda_map_tc(frac: NBBFractal, r: int, cx, cy, *,
 
 
 def stencil_step_blocks(layout: BlockLayout, state, workload=LIFE, *,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None, table=None):
     """Fused block-level workload step, v1 (neighbor-block staging)."""
     obs.inc("kernel.entry", op="stencil_step_blocks")
     return _stencil.stencil_step_blocks(layout, state, workload,
-                                        interpret=interpret)
+                                        interpret=interpret, table=table)
 
 
 def stencil_step_strips(layout: BlockLayout, state, workload=LIFE, *,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None, table=None):
     """Fused block-level workload step, v2 (strip halos)."""
     obs.inc("kernel.entry", op="stencil_step_strips")
     return _stencil.stencil_step_strips(layout, state, workload,
-                                        interpret=interpret)
+                                        interpret=interpret, table=table)
 
 
 def stencil_step_fused(layout: BlockLayout, state, workload=LIFE, *,
-                       interpret: Optional[bool] = None):
+                       interpret: Optional[bool] = None, table=None):
     """Fused block-level workload step, v3 (in-kernel strip reads)."""
     obs.inc("kernel.entry", op="stencil_step_fused")
     return _stencil.stencil_step_fused(layout, state, workload,
-                                       interpret=interpret)
+                                       interpret=interpret, table=table)
 
 
 def stencil_step_fused_k(layout: BlockLayout, state, workload=LIFE, *,
-                         k: int = 2, interpret: Optional[bool] = None):
+                         k: int = 2, interpret: Optional[bool] = None,
+                         table=None):
     """Fused block-level workload step, v4 (temporal fusion): k exact
     steps per launch on a depth-k halo tile held in VMEM. k <= rho."""
     obs.inc("kernel.entry", op="stencil_step_fused_k")
     return _stencil.stencil_step_fused_k(layout, state, workload, k=k,
-                                         interpret=interpret)
+                                         interpret=interpret, table=table)
 
 
 def stencil_step_mxu(layout: BlockLayout, state, workload=LIFE, *,
                      p: Optional[int] = None,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None, table=None):
     """Fused block-level workload step, v5 (MXU stencil-as-matmul on
     lane-packed macro-tiles). ``p`` overrides the macro-tile packing
     (blocks per macro-tile; None = lane heuristic — the autotuner
     persists per-config winners)."""
     obs.inc("kernel.entry", op="stencil_step_mxu")
     return _stencil.stencil_step_mxu(layout, state, workload, p=p,
-                                     interpret=interpret)
+                                     interpret=interpret, table=table)
 
 
 def stencil_step_mxu_k(layout: BlockLayout, state, workload=LIFE, *,
                        k: int = 2, p: Optional[int] = None,
-                       interpret: Optional[bool] = None):
+                       interpret: Optional[bool] = None, table=None):
     """Fused block-level workload step, v5 temporal fusion: k exact steps
     per MXU macro-tile launch (k <= rho). ``p`` overrides the macro-tile
     packing (None = lane heuristic)."""
     obs.inc("kernel.entry", op="stencil_step_mxu_k")
     return _stencil.stencil_step_mxu_k(layout, state, workload, k=k, p=p,
-                                       interpret=interpret)
+                                       interpret=interpret, table=table)
 
 
 def stencil_step_mxu_batched(layout: BlockLayout, states, workload=LIFE, *,
                              k: int = 1, p: Optional[int] = None,
-                             interpret: Optional[bool] = None):
+                             interpret: Optional[bool] = None, table=None):
     """v5 native batch grid: B simulations x k exact steps in one kernel
     dispatch over (B, n_macro_tiles); states (B, C?, n_blocks, rho, rho).
     ``p`` overrides the macro-tile packing (None = lane heuristic)."""
     obs.inc("kernel.entry", op="stencil_step_mxu_batched")
     return _stencil.stencil_step_mxu_batched(layout, states, workload, k=k,
-                                             p=p, interpret=interpret)
+                                             p=p, interpret=interpret,
+                                             table=table)
 
 
 def stencil3d_step_fused_k(layout, state, workload=None, *, k: int = 2,

@@ -10,10 +10,11 @@ parameterized by a single-channel ``StencilWorkload`` over the 26-cell
     halo — six face slabs covering the full window frame — is gathered
     once by XLA over the static neighbor table, then the kernel runs k
     update substeps on a (rho+2k)^3 window held in VMEM before the
-    single center write-back. Per-window occupancy is rebuilt in-kernel
-    from the shared periodic ``window_mask`` gated by a
-    scalar-prefetched 26-direction block-existence table (the 2D
-    substep mask discipline, per region).
+    single center write-back, for a tile of blocks per program.
+    Per-window occupancy is rebuilt in-kernel from the shared periodic
+    ``window_mask`` gated by each block's 26-direction existence row,
+    delivered as a VMEM block (the 2D substep mask discipline, per
+    region).
 
   * ``stencil3d_step_mxu_k`` (v5-style MXU stencil-as-matmul): the
     26-neighbor aggregation runs as banded matmul contractions *per
@@ -29,7 +30,7 @@ parameterized by a single-channel ``StencilWorkload`` over the 26-cell
 
 State is (n_blocks, rho, rho, rho) indexed [b, z, y, x] (single-channel
 workloads only, as the 3D engines). ``interpret=None`` auto-detects:
-compiled Mosaic on TPU, the Pallas interpreter elsewhere.
+compiled Mosaic on TPU, the Pallas interpreter on the CPU.
 """
 from __future__ import annotations
 
@@ -40,10 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compact3d import BlockLayout3D, halo_regions3
-from repro.kernels.common import resolve_interpret
+from repro.core.compact3d import BlockLayout3D
+from repro.kernels.common import (MXU_PRECISION, block_tiling,
+                                  compiler_params, resolve_interpret,
+                                  to_dtype, work_dtype)
 from repro.workloads.base import (MOORE3_DIRS, StencilWorkload,
                                   banded_operators)
 from repro.workloads.rules import LIFE3D
@@ -52,7 +54,7 @@ from repro.workloads.rules import LIFE3D
 DIR3_INDEX = {d: i for i, d in enumerate(MOORE3_DIRS)}
 
 
-def _gather_halo3_k(layout: BlockLayout3D, s: jnp.ndarray, k: int):
+def _gather_halo3_k(layout: BlockLayout3D, s: jnp.ndarray, k: int, table):
     """Depth-k halo slabs via slab-level XLA gathers over the static
     26-direction neighbor table (k <= rho, so every piece comes from one
     Moore neighbor). Returns six pieces whose union is the full window
@@ -70,7 +72,6 @@ def _gather_halo3_k(layout: BlockLayout3D, s: jnp.ndarray, k: int):
     """
     rho = layout.rho
     nc = s.shape[0]
-    table = layout.dev_neighbor_table
 
     def take(strip, d):  # strip (C, nb, ...), pre-sliced before the gather
         z = jnp.zeros((nc, 1) + strip.shape[2:], s.dtype)
@@ -96,19 +97,46 @@ def _gather_halo3_k(layout: BlockLayout3D, s: jnp.ndarray, k: int):
             take(s[:, :, :, :, :k], (1, 0, 0)))
 
 
-def _assemble_window(c, zlo, zhi, ylo, yhi, xlo, xhi, k):
-    """(C, rho^3) center + six face slabs -> (C, w^3) window."""
-    rho = c.shape[-1]
-    w = rho + 2 * k
-    padded = jnp.zeros(c.shape[:-3] + (w, w, w), c.dtype)
-    padded = padded.at[..., k:k + rho, k:k + rho, k:k + rho].set(c)
-    padded = padded.at[..., :k, :, :].set(zlo)
-    padded = padded.at[..., w - k:, :, :].set(zhi)
-    padded = padded.at[..., k:k + rho, :k, :].set(ylo)
-    padded = padded.at[..., k:k + rho, w - k:, :].set(yhi)
-    padded = padded.at[..., k:k + rho, k:k + rho, :k].set(xlo)
-    padded = padded.at[..., k:k + rho, k:k + rho, w - k:].set(xhi)
-    return padded
+def _assemble_window(c, zlo, zhi, ylo, yhi, xlo, xhi):
+    """(..., rho^3) center + six face slabs -> (..., w^3) window, by
+    concatenation (x faces, then y faces, then z faces)."""
+    mid = jnp.concatenate([xlo, c, xhi], axis=-1)
+    mid = jnp.concatenate([ylo, mid, yhi], axis=-2)
+    return jnp.concatenate([zlo, mid, zhi], axis=-3)
+
+
+def _gate3(ex: jnp.ndarray, rho: int, k: int) -> jnp.ndarray:
+    """(T, 26) existence rows -> (T, w, w, w) gate: 1 in the center and
+    in the halo regions of existing neighbors, 0 in ghost regions."""
+    t = ex.shape[0]
+    size = {-1: k, 0: rho, 1: k}
+
+    def region(dx, dy, dz):
+        shape = (t, size[dz], size[dy], size[dx])
+        if (dx, dy, dz) == (0, 0, 0):
+            return jnp.ones(shape, ex.dtype)
+        d = DIR3_INDEX[(dx, dy, dz)]
+        return jnp.broadcast_to(ex[:, d:d + 1].reshape(t, 1, 1, 1), shape)
+
+    return jnp.concatenate([
+        jnp.concatenate([
+            jnp.concatenate([region(dx, dy, dz) for dx in (-1, 0, 1)], -1)
+            for dy in (-1, 0, 1)], -2)
+        for dz in (-1, 0, 1)], -3)
+
+
+def _tile_bytes3(d: int, h: int, w: int) -> int:
+    """VMEM bytes of one (d, h, w) 32-bit volume, (8, 128)-tile padded."""
+    return d * (-(-h // 8) * 8) * (-(-w // 128) * 128) * 4
+
+
+def _pad_blocks(x: jnp.ndarray, n: int, axis: int = 1) -> jnp.ndarray:
+    extra = n - x.shape[axis]
+    if extra == 0:
+        return x
+    shape = list(x.shape)
+    shape[axis] = extra
+    return jnp.concatenate([x, jnp.zeros(shape, x.dtype)], axis=axis)
 
 
 # ======================================================================
@@ -116,21 +144,17 @@ def _assemble_window(c, zlo, zhi, ylo, yhi, xlo, xhi, k):
 # ======================================================================
 def _fused3_k_kernel(workload, k, ex_ref, c_ref, zlo_ref, zhi_ref, ylo_ref,
                      yhi_ref, xlo_ref, xhi_ref, wmask_ref, out_ref):
-    """One grid step = one block: assemble the (C, w, w, w) window,
-    rebuild its occupancy (periodic window mask x prefetched block
-    existence per region), then run the workload's k fused substeps."""
+    """One grid step = a tile of T blocks: assemble the (T, w, w, w)
+    windows, rebuild their occupancy (periodic window mask x each
+    block's existence row per region), then run the workload's k fused
+    substeps."""
+    wd = work_dtype(workload)
     rho = c_ref.shape[-1]
-    padded = _assemble_window(
-        c_ref[:, 0], zlo_ref[:, 0], zhi_ref[:, 0], ylo_ref[:, 0],
-        yhi_ref[:, 0], xlo_ref[:, 0], xhi_ref[:, 0], k)
-
-    i = pl.program_id(0)
-    mask = wmask_ref[...].astype(jnp.int32)
-    for d, (zs, ys, xs) in enumerate(halo_regions3(rho, k)):
-        mask = mask.at[zs, ys, xs].set(mask[zs, ys, xs] * ex_ref[i, d])
-
-    nxt = workload.tile_rule_k(padded[0], mask, k, ndim=3)[None]
-    out_ref[:, 0] = nxt.astype(out_ref.dtype)
+    padded = _assemble_window(*(r[0].astype(wd) for r in (
+        c_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref, xlo_ref, xhi_ref)))
+    mask = _gate3(ex_ref[...], rho, k) * wmask_ref[...]
+    nxt = workload.tile_rule_k(padded, mask, k, ndim=3, agg_dtype=wd)
+    out_ref[0] = to_dtype(nxt, out_ref.dtype)
 
 
 def stencil3d_step_fused_k(layout: BlockLayout3D, state: jnp.ndarray,
@@ -150,42 +174,42 @@ def stencil3d_step_fused_k(layout: BlockLayout3D, state: jnp.ndarray,
             f"fused 3D kernel needs k <= rho, got k={k} > rho={layout.rho} "
             "(use Squeeze3DBlockEngine.step_k for deeper halos)")
     layout.materialize()
-    _ = layout.dev_existence_table, layout.dev_window_mask(k)
-    return _stencil3d_step_fused_k(layout, state, workload, k,
+    return _stencil3d_step_fused_k(layout, state, layout.dev_neighbor_table,
+                                   workload, k,
                                    interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("layout", "workload", "k", "interpret"))
 def _stencil3d_step_fused_k(layout: BlockLayout3D, state: jnp.ndarray,
-                            workload: StencilWorkload, k: int, *,
+                            table, workload: StencilWorkload, k: int, *,
                             interpret: bool) -> jnp.ndarray:
     rho, nb = layout.rho, layout.n_blocks
-    s = state[None]  # single-channel: explicit channel axis internally
     w = rho + 2 * k
-    zlo, zhi, ylo, yhi, xlo, xhi = _gather_halo3_k(layout, s, k)
-    blk = lambda *shape: pl.BlockSpec(shape, lambda i, ex: (0, i) + (0,) * (len(shape) - 2))  # noqa: E731,E501
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            blk(1, 1, rho, rho, rho),
-            blk(1, 1, k, w, w), blk(1, 1, k, w, w),      # z faces
-            blk(1, 1, rho, k, w), blk(1, 1, rho, k, w),  # y faces
-            blk(1, 1, rho, rho, k), blk(1, 1, rho, rho, k),  # x faces
-            pl.BlockSpec((w, w, w), lambda i, ex: (0, 0, 0)),
-        ],
-        out_specs=blk(1, 1, rho, rho, rho),
-    )
+    tb, n_pad = block_tiling(nb, _tile_bytes3(w, w, w))
+    s = _pad_blocks(state[None], n_pad)   # explicit channel axis
+    pieces = [_pad_blocks(x, n_pad)
+              for x in _gather_halo3_k(layout, state[None], k, table)]
+    ex = _pad_blocks((table != layout.ghost).astype(jnp.int32), n_pad, 0)
+    blk = lambda *shape: pl.BlockSpec((1, tb) + shape, lambda i: (0, i) + (0,) * len(shape))  # noqa: E731,E501
     out = pl.pallas_call(
         functools.partial(_fused3_k_kernel, workload, k),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, nb, rho, rho, rho),
+        grid=(n_pad // tb,),
+        in_specs=[
+            pl.BlockSpec((tb, 26), lambda i: (i, 0)),
+            blk(rho, rho, rho),
+            blk(k, w, w), blk(k, w, w),          # z faces
+            blk(rho, k, w), blk(rho, k, w),      # y faces
+            blk(rho, rho, k), blk(rho, rho, k),  # x faces
+            pl.BlockSpec((w, w, w), lambda i: (0, 0, 0)),
+        ],
+        out_specs=blk(rho, rho, rho),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad, rho, rho, rho),
                                        workload.dtype),
         interpret=interpret,
-    )(layout.dev_existence_table, s, zlo, zhi, ylo, yhi, xlo, xhi,
-      layout.dev_window_mask(k))
-    return out[0]
+        compiler_params=compiler_params(interpret),
+    )(ex, s, *pieces, jnp.asarray(layout.window_mask(k).astype(np.int32)))
+    return out[0, :nb]
 
 
 # ======================================================================
@@ -194,25 +218,25 @@ def _stencil3d_step_fused_k(layout: BlockLayout3D, state: jnp.ndarray,
 @functools.lru_cache(maxsize=128)
 def _mxu3_operators(workload: StencilWorkload, w: int, p: int):
     """Static MXU contraction operands for one (workload, window, pack):
-    per rank-1 term of each nonzero z-plane of the weight tensor, a
-    banded ``R`` (w, w) row contraction and the block-diagonal (per
-    lane-packed slot) transpose ``CT`` (p*w, p*w) of its banded column
-    contraction, plus the static tuple of per-term z shifts — slab z's
-    aggregate is ``sum_t R_t @ X[z + dz_t] @ CT_t``."""
-    rms, cts, dzs = [], [], []
+    per rank-1 term of each nonzero z-plane of the weight tensor, the
+    3-tap row factor and the block-diagonal (per lane-packed slot)
+    transpose ``CT`` (p*w, p*w) of its banded column contraction, plus
+    the static tuple of per-term z shifts — slab z's aggregate is
+    ``sum_t rowmix_t(X[z + dz_t]) @ CT_t``."""
+    rows, cts, dzs = [], [], []
     for dz in (-1, 0, 1):
         terms = workload.weight_factors3[dz + 1]
         if not terms:
             continue
-        rm, cm = banded_operators(terms, w, np.float32)
-        for t in range(rm.shape[0]):
+        _, cm = banded_operators(terms, w, np.float32)
+        for t, (row, _) in enumerate(terms):
             ct = np.zeros((p * w, p * w), np.float32)
             for sl in range(p):
                 ct[sl * w:(sl + 1) * w, sl * w:(sl + 1) * w] = cm[t].T
-            rms.append(rm[t])
+            rows.append(tuple(row))
             cts.append(ct)
             dzs.append(dz)
-    return np.stack(rms), np.stack(cts), tuple(dzs)
+    return tuple(rows), np.stack(cts), tuple(dzs)
 
 
 def _zshift(x: jnp.ndarray, dz: int) -> jnp.ndarray:
@@ -228,69 +252,81 @@ def _zshift(x: jnp.ndarray, dz: int) -> jnp.ndarray:
     return jnp.concatenate([pad, x[..., :nz - 1, :, :]], axis=-3)
 
 
-def _mxu3_kernel(workload, k, p, dzs, ex_ref, c_ref, zlo_ref, zhi_ref,
-                 ylo_ref, yhi_ref, xlo_ref, xhi_ref, wmask_ref, r_ref,
+def _yshift(x: jnp.ndarray, d: int) -> jnp.ndarray:
+    """out[..., y, :] = x[..., y + d, :], zero past the window border."""
+    z = jnp.zeros(x.shape[:-2] + (1,) + x.shape[-1:], x.dtype)
+    if d > 0:
+        return jnp.concatenate([x[..., 1:, :], z], axis=-2)
+    return jnp.concatenate([z, x[..., :-1, :]], axis=-2)
+
+
+def _mxu3_kernel(workload, k, p, rows, dzs, ex_ref, c_ref, zlo_ref,
+                 zhi_ref, ylo_ref, yhi_ref, xlo_ref, xhi_ref, wmask_ref,
                  ct_ref, out_ref):
-    """One grid step = one macro-tile: assemble the (w, w, P*w)
-    lane-packed window (P block slots side by side along x), rebuild
-    each slot's occupancy from the shared periodic window mask gated by
-    its prefetched 26-direction existence row, then run k substeps whose
-    26-neighbor aggregation is the per-z-slab banded matmul sum."""
+    """One grid step = a tile of T macro-tiles: assemble the (T, w, wr,
+    P*w) lane-packed windows (P block slots side by side along x, rows
+    padded to wr, a multiple of 8), rebuild each slot's occupancy from
+    the shared periodic window mask gated by its existence row, then
+    run k substeps whose 26-neighbor aggregation is, per rank-1 term of
+    each z-plane, a 3-tap y combination of the z-shifted slabs followed
+    by ONE MXU contraction of all rows with the banded column operand."""
     rho = c_ref.shape[-2]
     w = rho + 2 * k
-    c = c_ref[0, 0]                          # (rho, rho, P*rho)
-    zlo, zhi = zlo_ref[0, 0], zhi_ref[0, 0]  # (k, w, P*w)
-    ylo, yhi = ylo_ref[0, 0], yhi_ref[0, 0]  # (rho, k, P*w)
-    xlo, xhi = xlo_ref[0, 0], xhi_ref[0, 0]  # (rho, rho, P*k)
-    i = pl.program_id(0)
+    wr = -(-w // 8) * 8
+    f32 = jnp.float32
 
-    cur = jnp.zeros((w, w, p * w), c.dtype)
-    mask = jnp.zeros((w, w, p * w), jnp.int32)
-    wm = wmask_ref[...].astype(jnp.int32)
+    def ld(ref):
+        return to_dtype(ref[0], f32)         # (T, d, h, cols)
+
+    c, zlo, zhi, ylo, yhi, xlo, xhi = (ld(r) for r in (
+        c_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref, xlo_ref, xhi_ref))
+    ex = ex_ref[...]                         # (T, 26P)
+    wm = wmask_ref[...]
+    wins, masks = [], []
     for sl in range(p):
-        b0 = sl * w
-        win = _assemble_window(
-            c[:, :, sl * rho:(sl + 1) * rho],
-            zlo[:, :, sl * w:(sl + 1) * w], zhi[:, :, sl * w:(sl + 1) * w],
-            ylo[:, :, sl * w:(sl + 1) * w], yhi[:, :, sl * w:(sl + 1) * w],
-            xlo[:, :, sl * k:(sl + 1) * k], xhi[:, :, sl * k:(sl + 1) * k],
-            k)
-        cur = cur.at[:, :, b0:b0 + w].set(win)
-        m = wm
-        for d, (zs, ys, xs) in enumerate(halo_regions3(rho, k)):
-            m = m.at[zs, ys, xs].set(m[zs, ys, xs] * ex_ref[i * p + sl, d])
-        mask = mask.at[:, :, b0:b0 + w].set(m)
-
-    rm = r_ref[...]                          # (T, w, w) f32
-    ct = ct_ref[...]                         # (T, P*w, P*w) f32
+        sw, sk = slice(sl * w, (sl + 1) * w), slice(sl * k, (sl + 1) * k)
+        wins.append(_assemble_window(
+            c[..., sl * rho:(sl + 1) * rho], zlo[..., sw], zhi[..., sw],
+            ylo[..., sw], yhi[..., sw], xlo[..., sk], xhi[..., sk]))
+        masks.append(_gate3(ex[:, 26 * sl:26 * (sl + 1)], rho, k) * wm)
+    cur = jnp.concatenate(wins, axis=-1)     # (T, w, w, P*w)
+    mask = jnp.concatenate(masks, axis=-1)
+    if wr > w:  # zero rows past the window: outside every live region
+        cur = jnp.concatenate(
+            [cur, jnp.zeros(cur.shape[:-2] + (wr - w, p * w), f32)], -2)
+        mask = jnp.concatenate(
+            [mask, jnp.zeros(mask.shape[:-2] + (wr - w, p * w),
+                             mask.dtype)], -2)
+    t = cur.shape[0]
+    ct = ct_ref[...]                         # (n_terms, P*w, P*w) f32
     int_agg = jnp.issubdtype(jnp.dtype(workload.agg_dtype), jnp.integer)
     for _ in range(k):
-        x = cur.astype(jnp.float32)
-        agg = jnp.zeros((w, w, p * w), jnp.float32)
-        for t, dz in enumerate(dzs):
-            xs = _zshift(x, dz)              # (w_z, w_y, P*w_x) slabs
-            y = jnp.einsum("ij,zjx->zix", rm[t], xs)
-            agg = agg + jnp.einsum("zix,xm->zim", y, ct[t])
-        # integer CA aggregates: the f32 matmuls reconstruct integer
+        up, dn = _yshift(cur, -1), _yshift(cur, 1)
+        agg = None
+        for i, (row, dz) in enumerate(zip(rows, dzs)):
+            y = _zshift(row[0] * up + row[1] * cur + row[2] * dn, dz)
+            y = jnp.dot(y.reshape(t * w * wr, p * w), ct[i],
+                        precision=MXU_PRECISION,
+                        preferred_element_type=f32)
+            agg = y if agg is None else agg + y
+        agg = agg.reshape(t, w, wr, p * w)
+        # integer CA aggregates: the f32 products reconstruct integer
         # neighbor counts to ~1e-5, so nearest-int rounding is bit-exact
-        agg = (jnp.rint(agg).astype(workload.agg_dtype) if int_agg
-               else agg.astype(workload.agg_dtype))
-        cur = workload.apply(cur, agg, mask).astype(c.dtype)
+        if int_agg:
+            agg = jnp.rint(agg).astype(jnp.int32)
+        cur = workload.apply(cur, agg, mask).astype(f32)
 
-    out = jnp.zeros((rho, rho, p * rho), out_ref.dtype)
-    for sl in range(p):
-        out = out.at[:, :, sl * rho:(sl + 1) * rho].set(
-            cur[k:k + rho, k:k + rho,
-                sl * w + k:sl * w + k + rho].astype(out.dtype))
-    out_ref[0, 0] = out
+    out = jnp.concatenate(
+        [cur[:, k:k + rho, k:k + rho, sl * w + k:sl * w + k + rho]
+         for sl in range(p)], axis=-1)       # (T, rho, rho, P*rho)
+    out_ref[0] = to_dtype(out, out_ref.dtype)
 
 
 def _pack_macro3(arr: jnp.ndarray, nb: int, p: int, n_macro: int):
     """(L, nb, d, h, c) per-block slabs -> (L, n_macro, d, h, P*c)
     lane-packed macro slabs (zero-filled padding slots past nb)."""
     lead, _, d, h, cols = arr.shape
-    pad = jnp.zeros((lead, n_macro * p - nb, d, h, cols), arr.dtype)
-    a = jnp.concatenate([arr, pad], axis=1)
+    a = _pad_blocks(arr, n_macro * p)
     a = a.reshape(lead, n_macro, p, d, h, cols).transpose(0, 1, 3, 4, 2, 5)
     return a.reshape(lead, n_macro, d, h, p * cols)
 
@@ -310,63 +346,57 @@ def stencil3d_step_mxu_k(layout: BlockLayout3D, state: jnp.ndarray,
             f"mxu 3D kernel needs k <= rho, got k={k} > rho={layout.rho} "
             "(use Squeeze3DBlockEngine.step_k for deeper halos)")
     # resolve the packing override to its concrete P so the jit cache
-    # and the layout memos key on one value
+    # keys on one value
     p = layout.macro_tiles(k, p=p)[0]
     layout.materialize()
-    _ = layout.dev_existence_padded(k, p=p), layout.dev_window_mask(k)
-    _ = _mxu3_operators(workload, layout.rho + 2 * k, p)
-    return _stencil3d_step_mxu_k(layout, state, workload, k, p,
+    return _stencil3d_step_mxu_k(layout, state, layout.dev_neighbor_table,
+                                 workload, k, p,
                                  interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("layout", "workload", "k", "p",
                                     "interpret"))
-def _stencil3d_step_mxu_k(layout: BlockLayout3D, state: jnp.ndarray,
-                          workload: StencilWorkload, k: int,
-                          p: Optional[int] = None, *,
+def _stencil3d_step_mxu_k(layout: BlockLayout3D, state: jnp.ndarray, table,
+                          workload: StencilWorkload, k: int, p: int, *,
                           interpret: bool) -> jnp.ndarray:
     rho, nb = layout.rho, layout.n_blocks
     w = rho + 2 * k
-    p, n_macro, _ = layout.macro_tiles(k, p=p)
+    wr = -(-w // 8) * 8
+    n_macro = -(-nb // p)
+    tm, n_macro = block_tiling(n_macro, _tile_bytes3(w, wr, p * w))
     s = state[None]
-    pieces = _gather_halo3_k(layout, s, k)
+    pieces = _gather_halo3_k(layout, s, k, table)
 
     def pack(arr):
         return _pack_macro3(arr, nb, p, n_macro)
 
-    cm = pack(s)
-    zlom, zhim, ylom, yhim, xlom, xhim = (pack(a) for a in pieces)
-    rm, ct, dzs = _mxu3_operators(workload, w, p)
-    n_terms = rm.shape[0]
+    ex = _pad_blocks((table != layout.ghost).astype(jnp.int32),
+                     n_macro * p, 0).reshape(n_macro, 26 * p)
+    rows, ct, dzs = _mxu3_operators(workload, w, p)
+    n_terms = ct.shape[0]
 
     def blk(d, h, cols):
-        return pl.BlockSpec((1, 1, d, h, cols),
-                            lambda i, ex: (0, i, 0, 0, 0))
+        return pl.BlockSpec((1, tm, d, h, cols), lambda i: (0, i, 0, 0, 0))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_macro,),
+    out = pl.pallas_call(
+        functools.partial(_mxu3_kernel, workload, k, p, rows, dzs),
+        grid=(n_macro // tm,),
         in_specs=[
+            pl.BlockSpec((tm, 26 * p), lambda i: (i, 0)),
             blk(rho, rho, p * rho),
             blk(k, w, p * w), blk(k, w, p * w),          # z faces
             blk(rho, k, p * w), blk(rho, k, p * w),      # y faces
             blk(rho, rho, p * k), blk(rho, rho, p * k),  # x faces
-            pl.BlockSpec((w, w, w), lambda i, ex: (0, 0, 0)),
-            pl.BlockSpec((n_terms, w, w), lambda i, ex: (0, 0, 0)),
-            pl.BlockSpec((n_terms, p * w, p * w),
-                         lambda i, ex: (0, 0, 0)),
+            pl.BlockSpec((w, w, w), lambda i: (0, 0, 0)),
+            pl.BlockSpec((n_terms, p * w, p * w), lambda i: (0, 0, 0)),
         ],
         out_specs=blk(rho, rho, p * rho),
-    )
-    out = pl.pallas_call(
-        functools.partial(_mxu3_kernel, workload, k, p, dzs),
-        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, n_macro, rho, rho, p * rho),
                                        workload.dtype),
         interpret=interpret,
-    )(layout.dev_existence_padded(k, p=p), cm, zlom, zhim, ylom, yhim,
-      xlom, xhim, layout.dev_window_mask(k), jnp.asarray(rm),
-      jnp.asarray(ct))
+        compiler_params=compiler_params(interpret),
+    )(ex, pack(s), *(pack(a) for a in pieces),
+      jnp.asarray(layout.window_mask(k).astype(np.int32)), jnp.asarray(ct))
     out = out.reshape(n_macro, rho, rho, p, rho).transpose(0, 3, 1, 2, 4)
     return out.reshape(n_macro * p, rho, rho, rho)[:nb]

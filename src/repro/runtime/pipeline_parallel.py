@@ -41,9 +41,10 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: Array, *,
         mbs = x_all.reshape(n_microbatches, mb, *x_all.shape[1:])
         # mark the loop carries as pod-varying up front (each stage holds
         # different values), else the fori carry types mismatch
-        from repro.utils.jax_compat import pvary
-        carry_in = pvary(jnp.zeros_like(mbs[0]), (axis,))
-        outputs = pvary(jnp.zeros_like(mbs), (axis,))
+        carry_in = jax.lax.pcast(jnp.zeros_like(mbs[0]), (axis,),
+                                 to="varying")
+        outputs = jax.lax.pcast(jnp.zeros_like(mbs), (axis,),
+                                to="varying")
 
         def tick(t, state):
             carry, outs = state
@@ -68,8 +69,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: Array, *,
             jnp.where(stage == n_stages - 1, outputs, 0.0), axis)
         return outputs.reshape(b, *x_all.shape[1:])
 
-    from repro.utils.jax_compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P())

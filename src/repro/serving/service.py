@@ -359,15 +359,25 @@ class FractalService:
                                       donate=True)
                 return jax.block_until_ready(out)
 
-            # a batch shape this bucket has not launched yet pays XLA
-            # compilation on this call — give it the compile grace so a
-            # trace never reads as a hang (steady state gets the tight
-            # threshold back)
-            timeout = (cfg.hang_threshold_s if len(rows) in warm
-                       else max(cfg.hang_threshold_s,
-                                cfg.compile_grace_s))
-            self.watchdog.start_step()
+            # a batch shape this bucket has not launched yet is compiled
+            # ahead of its launch, outside the hang clock: compilation is
+            # set-up, however long it takes, and never reads as a hang.
+            # Kinds that compile inside their own run (dist-*) get the
+            # compile grace on that first launch instead.
+            cold = len(rows) not in warm
+
+            def compile_ahead(states=states):
+                return self.runner.compile_run(
+                    bucket, states, donate=True, frac=rep.frac,
+                    workload=rep.workload)
+
             try:
+                if cold and await run_in(self._executor,
+                                         compile_ahead) is not None:
+                    cold = False
+                timeout = (max(cfg.hang_threshold_s, cfg.compile_grace_s)
+                           if cold else cfg.hang_threshold_s)
+                self.watchdog.start_step()
                 out = await asyncio.wait_for(
                     run_in(self._executor, work), timeout=timeout)
             except asyncio.TimeoutError:

@@ -140,10 +140,10 @@ class ServiceConfig:
     backoff_seed: int = 0
     # ---- watchdog (hang detection on one segment's wall time)
     hang_threshold_s: float = 10.0
-    #: wall-time allowance when a segment's batch shape has not run
-    #: before (first launch per (bucket, B) pays XLA compilation, which
-    #: dwarfs steady-state segments and must not read as a hang); also
-    #: applies to the first launch after an engine restart (recompile)
+    #: wall-time allowance of a segment whose batch shape has not run
+    #: before and could not be compiled ahead of its launch (the dist-*
+    #: kinds compile inside their own run); every other kind compiles
+    #: ahead, outside the hang clock, whatever the compile takes
     compile_grace_s: float = 60.0
     # ---- circuit breaker
     breaker_threshold: int = 5     # consecutive failures to open

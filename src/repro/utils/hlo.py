@@ -131,8 +131,7 @@ class Roofline:
 def roofline_from_compiled(compiled, n_chips: int,
                            model_flops: float = 0.0,
                            hlo_text: Optional[str] = None) -> Roofline:
-    from repro.utils.jax_compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled) or {}
+    ca = compiled.cost_analysis() or {}
     txt = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes(txt)
     return Roofline(

@@ -18,8 +18,10 @@ core/distributed.py) and the Pallas kernels stay rule-agnostic.
                                  occupancy (holes/boundary), or None when
                                  the caller's domain has no holes (cell
                                  engine: every compact cell is real);
-  * ``init(key, shape)``       — the initial-state distribution over the
-                                 *unmasked* spatial domain (engines mask).
+  * ``init(bits)``             — the initial-state distribution over the
+                                 *unmasked* spatial domain (engines mask),
+                                 drawn from one uint32 ``cell_bits`` word
+                                 per cell.
 
 Out-of-fractal and hole neighbors always contribute 0 to the aggregate —
 dead cells for CA rules, Dirichlet-0 boundaries for the PDE rules — which
@@ -83,8 +85,9 @@ class StencilWorkload:
         """
         raise NotImplementedError
 
-    def init(self, key, shape) -> Array:
-        """Initial state over spatial ``shape`` ((C, *shape) if C > 1)."""
+    def init(self, bits: Array) -> Array:
+        """Initial state from one uint32 random word per cell (``bits``
+        has the spatial shape; the result is (C, *shape) if C > 1)."""
         raise NotImplementedError
 
     # ------------------------------------------------------------ conveniences
@@ -144,15 +147,19 @@ class StencilWorkload:
         untouched — dataclass hashing reads fields, not ``__dict__``."""
         return svd_rank1_terms(self.weights3x3)
 
-    def tile_rule(self, center: Array, padded: Array, mask) -> Array:
+    def tile_rule(self, center: Array, padded: Array, mask,
+                  agg_dtype=None) -> Array:
         """One update on a halo-padded tile: ``center`` (C?, h, w), ``padded``
         (C?, h+2, w+2). This is the traced function the Pallas kernels call
-        in place of the old hard-coded life rule."""
-        agg = weighted_moore_agg(padded, self.weights2d, self.agg_dtype)
+        in place of the old hard-coded life rule. ``agg_dtype`` overrides
+        the workload's accumulation dtype (the kernels accumulate on
+        32-bit lanes)."""
+        agg = weighted_moore_agg(padded, self.weights2d,
+                                 agg_dtype or self.agg_dtype)
         return self.apply(center, agg, mask)
 
     def tile_rule_k(self, padded: Array, halo_mask, k: int,
-                    ndim: int = 2) -> Array:
+                    ndim: int = 2, agg_dtype=None) -> Array:
         """``k`` fused updates on a depth-``k`` padded tile (temporal
         blocking). ``padded`` is (C?, h+2k, w+2k); each substep updates the
         current window's interior and the window shrinks by one ring, so
@@ -164,14 +171,17 @@ class StencilWorkload:
         mid-flight, not just at the final write.
 
         ``ndim=3`` runs the same discipline on a (C?, d+2k, h+2k, w+2k)
-        volume over the 26-direction aggregate (the 3D block engines)."""
+        volume over the 26-direction aggregate (the 3D block engines).
+        ``agg_dtype`` overrides the accumulation dtype as in
+        ``tile_rule``."""
         crop = (Ellipsis,) + (slice(1, -1),) * ndim
         agg_of = weighted_moore_agg if ndim == 2 else weighted_moore_agg3
         weights = self.weights2d if ndim == 2 else self.weights3d
+        agg_dtype = agg_dtype or self.agg_dtype
         cur = padded
         for _ in range(k):
             center = cur[crop]
-            agg = agg_of(cur, weights, self.agg_dtype)
+            agg = agg_of(cur, weights, agg_dtype)
             if halo_mask is not None:
                 halo_mask = halo_mask[crop]
             cur = self.apply(center, agg, halo_mask)
@@ -179,6 +189,36 @@ class StencilWorkload:
 
     def masked(self, state: Array, mask) -> Array:
         return state if mask is None else state * mask.astype(state.dtype)
+
+
+def _mix32(x: Array) -> Array:
+    """The lowbias32 integer finalizer (uint32 -> uint32, bijective)."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def cell_bits(seed: int, *coords: Array) -> Array:
+    """One uint32 random word per cell, a pure function of ``seed`` and
+    the cell's EXPANDED coordinates (x, y[, z]).
+
+    Every engine draws its initial state from these words at its own
+    cells' coordinates, so the expanded baselines and the compact
+    engines start from the same field without any of them materializing
+    another's layout: a block state at r=17 is seeded on the device in
+    block space, never through the 2^34-cell embedding."""
+    h = _mix32(jnp.asarray(seed).astype(jnp.uint32)
+               ^ jnp.uint32(0x9E3779B9))
+    for c in coords:
+        h = _mix32(h ^ jnp.asarray(c).astype(jnp.uint32))
+    return h
+
+
+def uniform_from_bits(bits: Array) -> Array:
+    """float32 in [0, 1) from the top 24 bits of a uint32 word."""
+    return (bits >> 8).astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
 #: which pieces of a Moore halo a single radius-1 update actually reads:

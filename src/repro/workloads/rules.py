@@ -17,10 +17,9 @@ from __future__ import annotations
 import dataclasses
 from typing import FrozenSet
 
-import jax
 import jax.numpy as jnp
 
-from repro.workloads.base import StencilWorkload
+from repro.workloads.base import StencilWorkload, uniform_from_bits
 
 Array = jnp.ndarray
 
@@ -34,8 +33,10 @@ def life_rule(alive: Array, neighbors: Array) -> Array:
 
 
 def _count_in(agg: Array, counts: FrozenSet[int]) -> Array:
-    """Boolean: agg is one of the (static) counts."""
-    hit = jnp.zeros(agg.shape, bool)
+    """Boolean: agg is one of the (static) counts. Built from
+    comparisons only (no boolean constant array, which Mosaic cannot
+    materialize)."""
+    hit = agg < 0  # all False: counts are never negative
     for c in sorted(counts):
         hit = hit | (agg == c)
     return hit
@@ -51,14 +52,19 @@ class TotalisticCA(StencilWorkload):
     born: FrozenSet[int] = frozenset({3})
     survive: FrozenSet[int] = frozenset({2, 3})
 
+    #: neighbor counts are at most 26 (3D Moore), so uint8 holds them
+    #: exactly: a haloed block window is never widened past the state's
+    #: bytes (the Pallas kernels still count on 32-bit lanes)
+    agg_dtype = jnp.uint8
+
     def apply(self, center, agg, mask):
         alive = center > 0
-        nxt = jnp.where(alive, _count_in(agg, self.survive),
-                        _count_in(agg, self.born)).astype(jnp.uint8)
+        nxt = ((alive & _count_in(agg, self.survive))
+               | (~alive & _count_in(agg, self.born))).astype(center.dtype)
         return self.masked(nxt, mask)
 
-    def init(self, key, shape):
-        return jax.random.bernoulli(key, 0.5, shape).astype(jnp.uint8)
+    def init(self, bits):
+        return (bits >> 31).astype(jnp.uint8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,8 +95,8 @@ class HeatDiffusion(StencilWorkload):
         nxt = u + self.alpha * (agg - self.degree * u)
         return self.masked(nxt, mask)
 
-    def init(self, key, shape):
-        return jax.random.uniform(key, shape, jnp.float32)
+    def init(self, bits):
+        return uniform_from_bits(bits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,8 +136,8 @@ class GrayScott(StencilWorkload):
         nv = v + self.dv * lap_v + uvv - (self.feed + self.kill) * v
         return self.masked(jnp.stack([nu, nv]), mask)
 
-    def init(self, key, shape):
-        seeds = jax.random.bernoulli(key, 0.02, shape)
+    def init(self, bits):
+        seeds = uniform_from_bits(bits) < 0.02
         u = 1.0 - 0.5 * seeds.astype(jnp.float32)
         v = 0.25 * seeds.astype(jnp.float32)
         return jnp.stack([u, v])

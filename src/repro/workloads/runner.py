@@ -10,8 +10,8 @@ provides the building block:
     batch axis of independent states (B simulations advance in one XLA
     call); the 'pallas-mxu' kind instead dispatches ONE kernel over a
     native (B, n_macro_tiles) grid (``supports_native_batch`` on the
-    engine), sharing the scalar-prefetched block tables across the batch
-    — the vmap path stays as the fallback for every other kind;
+    engine), sharing the block tables across the batch — the vmap path
+    stays as the fallback for every other kind;
   * fused multi-step serving: ``run`` tiles the step count into
     floor(steps/k) vmapped k-step launches (temporal fusion over the
     engines' depth-k halos) plus a single-step remainder; the fusion
@@ -104,6 +104,9 @@ class _Entry:
     batched_step: callable
     batched_run: callable
     batched_run_donated: callable
+    #: the engine's device tables, passed to every compiled call as an
+    #: argument (never closed over: jit would bake them in as constants)
+    ops: object = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -214,18 +217,18 @@ class BatchedRunner:
             # the Python-side collective counters would only run at trace
             # time. Its step/run handle (B, C?, nb_padded, rho, rho)
             # natively (one batched strip all-gather per launch).
-            return _Entry(engine, engine.step_batched,
-                          lambda states, steps: engine.run(
+            return _Entry(engine,
+                          lambda states, ops: engine.step_batched(states),
+                          lambda states, steps, ops: engine.run(
                               states, int(steps)),
-                          lambda states, steps: engine.run(
+                          lambda states, steps, ops: engine.run(
                               states, int(steps), donate=True))
         fused = spec.is_block and k > 1
         stats = self.stats
         # the v5 'mxu' engine advances the whole batch through ONE kernel
-        # dispatch over a (B, n_macro_tiles) grid — the scalar-prefetched
-        # tables are shared across the batch instead of re-staged per
-        # simulation by a vmap of pallas_call; every other kind keeps the
-        # vmap path
+        # dispatch over a (B, n_macro_tiles) grid — the block tables are
+        # shared across the batch instead of re-staged per simulation by a
+        # vmap of pallas_call; every other kind keeps the vmap path
         native = getattr(engine, "supports_native_batch", False)
 
         def trace_tick():
@@ -236,43 +239,42 @@ class BatchedRunner:
             obs.inc("runner.trace", kind=kind, workload=spec.workload,
                     k=k)
 
-        def traced_step(state):
+        def traced_step(state, ops):
             trace_tick()
-            return engine.step(state)
+            # engines without device tables (cell/bb/lambda) take none
+            return engine.step(state, ops) if ops else engine.step(state)
 
-        def traced_step_k(state):
+        def traced_step_k(state, ops):
             trace_tick()
-            return engine.step_k(state, k)
+            return engine.step_k(state, k, ops)
 
-        def traced_batch_step(states):
+        def traced_batch_step(states, ops):
             trace_tick()
-            return engine.step_batched(states)
+            return engine.step_batched(states, ops)
 
-        def traced_batch_step_k(states):
+        def traced_batch_step_k(states, ops):
             trace_tick()
-            return engine.step_k_batched(states, k)
+            return engine.step_k_batched(states, k, ops)
 
-        batched_step = jax.jit(
-            traced_batch_step if native else jax.vmap(traced_step))
+        def vmapped(fn):  # batch axis on the states only, shared tables
+            return jax.vmap(fn, in_axes=(0, None))
 
-        def _run(states, steps):
-            body = traced_batch_step if native else jax.vmap(traced_step)
+        body = traced_batch_step if native else vmapped(traced_step)
+        body_k = (traced_batch_step_k if native
+                  else vmapped(traced_step_k))
+
+        def _run(states, steps, ops):
             if fused:
-                body_k = (traced_batch_step_k if native
-                          else jax.vmap(traced_step_k))
                 states = jax.lax.fori_loop(
-                    0, steps // k, lambda _, s: body_k(s), states)
+                    0, steps // k, lambda _, s: body_k(s, ops), states)
                 return jax.lax.fori_loop(
-                    0, steps % k, lambda _, s: body(s), states)
+                    0, steps % k, lambda _, s: body(s, ops), states)
             return jax.lax.fori_loop(
-                0, steps, lambda _, s: body(s), states)
+                0, steps, lambda _, s: body(s, ops), states)
 
-        if fused and kind == "block":
-            # XLA step_k tables, outside traces; the pallas kinds build
-            # their (smaller) v4 set in the kernel entry point
-            engine.layout.materialize_halo(k)
-        return _Entry(engine, batched_step, jax.jit(_run),
-                      jax.jit(_run, donate_argnums=0))
+        ops = engine.operands() if hasattr(engine, "operands") else {}
+        return _Entry(engine, jax.jit(body), jax.jit(_run),
+                      jax.jit(_run, donate_argnums=0), ops)
 
     def _insert(self, key: Key, entry: _Entry) -> _Entry:
         """Shared cache insert + build accounting + LRU eviction."""
@@ -374,7 +376,8 @@ class BatchedRunner:
         if isinstance(kind, EngineSpec) and states is None:
             states, frac = frac, None  # step(spec, states) form
         res = self._resolve(kind, frac, r, m, workload, None, mesh, axis)
-        return self._get(res).batched_step(states)
+        entry = self._get(res)
+        return entry.batched_step(states, entry.ops)
 
     def run(self, kind, frac=None, r: Optional[int] = None,
             states: Optional[Array] = None, steps: Optional[int] = None,
@@ -405,7 +408,7 @@ class BatchedRunner:
         label = res.spec.kind
         fn = entry.batched_run_donated if donate else entry.batched_run
         with obs.span("runner.run", kind=label, steps=int(steps)):
-            out = fn(states, jnp.asarray(steps, jnp.int32))
+            out = fn(states, jnp.asarray(steps, jnp.int32), entry.ops)
         if t0 is not None:
             obs.observe("runner.run.seconds",
                         time.perf_counter() - t0, kind=label)
@@ -416,6 +419,23 @@ class BatchedRunner:
             if donate:
                 obs.inc("runner.donated_runs", kind=label)
         return out
+
+    def compile_run(self, spec: EngineSpec, states, donate: bool = False,
+                    frac=None, workload: Optional[StencilWorkload] = None,
+                    mesh=None):
+        """Compile ``run(spec, states, ...)`` for this batch shape ahead
+        of the launch, so the launch itself starts warm; returns the
+        compiled executable. None for kinds that compile inside their
+        own ``run`` (the 'dist-*' engines own their jit cache and tile
+        their launches)."""
+        res = self._resolve(spec, frac, None, 0, workload, None, mesh)
+        entry = self._get(res)
+        fn = entry.batched_run_donated if donate else entry.batched_run
+        if not hasattr(fn, "lower"):
+            return None
+        return fn.lower(jax.ShapeDtypeStruct(states.shape, states.dtype),
+                        jax.ShapeDtypeStruct((), jnp.int32),
+                        entry.ops).compile()
 
     def to_expanded(self, kind, frac=None, r: Optional[int] = None,
                     states: Optional[Array] = None, m: int = 0,

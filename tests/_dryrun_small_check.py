@@ -10,7 +10,7 @@ import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.utils.jax_compat import make_mesh  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro import configs  # noqa: E402
 from repro.launch import specs as specs_lib  # noqa: E402
@@ -42,7 +42,8 @@ def _shrink_shapes():
 def main():
     assert jax.device_count() == 8
     _shrink_shapes()
-    mesh = make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     # monkeypatch the registry to smoke configs
     real_get = configs.get_config

@@ -8,7 +8,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax  # noqa: E402
 
-from repro.utils.jax_compat import make_mesh  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
@@ -22,7 +22,8 @@ from repro.utils.sharding import param_specs  # noqa: E402
 def main(ckpt_dir):
     assert jax.device_count() == 8
     cfg = configs.get_smoke_config("smollm-135m")
-    mesh = make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     params_like = model_lib.init_params(jax.random.PRNGKey(0), cfg)
     opt_like = adamw.init(adamw.AdamWConfig(), params_like)

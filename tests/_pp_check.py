@@ -9,12 +9,13 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.runtime.pipeline_parallel import pipeline_apply, stack_stages  # noqa: E402
-from repro.utils.jax_compat import make_mesh  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 
 def main():
     assert jax.device_count() == 4
-    mesh = make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",),
+                         axis_types=(AxisType.Auto,))
     n_layers, d, b = 8, 32, 16
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (n_layers, d, d)) * 0.3

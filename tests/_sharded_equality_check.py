@@ -11,7 +11,7 @@ import dataclasses  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.utils.jax_compat import make_mesh  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
@@ -53,7 +53,8 @@ def check(arch, seq_shard=False, tol=2e-3):
     p_ref, _, m_ref = step_ref(params, opt, batch)
 
     # sharded execution on a (4, 2) mesh with the production specs
-    mesh = make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     p_sh = specs_lib.param_shardings(params, mesh)
     params_s = jax.device_put(params, p_sh)
     o_struct = jax.eval_shape(lambda: opt)

@@ -70,3 +70,30 @@ def test_variants_agree_many_steps():
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s3))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s4))
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    """Pallas kernels compile on TPU, interpret on the CPU, and never fall
+    back to the interpreter silently on any other backend."""
+    import jax
+
+    from repro.kernels import common
+    for backend, want in (("tpu", False), ("cpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert common.default_interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(common.NoKernelBackendError):
+        common.resolve_interpret(None)
+    assert common.resolve_interpret(True) is True
+
+
+@pytest.mark.parametrize("entry", ["blocks", "fused"])
+def test_smem_table_kernels_refuse_large_layouts(entry):
+    """v1/v3 scalar-prefetch the whole (nb, 8) neighbor table into SMEM:
+    past its budget a compiled run raises a named error before tracing."""
+    from repro.kernels.common import KernelCapacityError
+    layout = BlockLayout(fractals.SIERPINSKI, 13, 4)  # nb = 3^9
+    fn = {"blocks": ops.stencil_step_blocks,
+          "fused": ops.stencil_step_fused}[entry]
+    with pytest.raises(KernelCapacityError, match="SMEM"):
+        fn(layout, None, interpret=False)
