@@ -171,6 +171,9 @@ class FractalService:
         while self._tasks:
             await asyncio.gather(*list(self._tasks),
                                  return_exceptions=True)
+            # a gather over tasks that are all done completes without
+            # yielding, so let their done callbacks empty _tasks
+            await asyncio.sleep(0)
         self._shed_all("preempted" if self._preempted() else "rejected")
         if self._executor is not None:
             self._executor.shutdown(wait=False)

@@ -26,7 +26,7 @@ def test_chaos_matrix_recovers_on_8_device_mesh(tmp_path):
     out = subprocess.run(
         [sys.executable, str(script), "--smoke",
          "--max-recovery-s", "60", "--out", str(out_json)],
-        env=env, capture_output=True, text=True, timeout=900)
+        env=env, capture_output=True, text=True, timeout=80)
     assert out.returncode == 0, (
         f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}")
     assert "CHAOS_DIST_OK" in out.stdout

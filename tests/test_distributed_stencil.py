@@ -18,6 +18,6 @@ def test_distributed_engine_matches_single_device():
     env["PYTHONPATH"] = str(repo / "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     out = subprocess.run([sys.executable, str(script)], env=env,
-                         capture_output=True, text=True, timeout=900)
+                         capture_output=True, text=True, timeout=170)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
     assert "DISTRIBUTED_OK" in out.stdout

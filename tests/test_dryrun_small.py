@@ -13,7 +13,7 @@ def test_dryrun_machinery_small_mesh():
     env["PYTHONPATH"] = str(repo / "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     out = subprocess.run([sys.executable, str(script)], env=env,
-                         capture_output=True, text=True, timeout=1800)
+                         capture_output=True, text=True, timeout=90)
     assert out.returncode == 0, \
         f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-4000:]}"
     assert "DRYRUN_SMALL_OK" in out.stdout

@@ -30,7 +30,7 @@ def test_one_device_checkpoint_restores_on_eight(tmp_path):
         "PYTHONPATH", "")
     out = subprocess.run([sys.executable, str(script), str(tmp_path)],
                          env=env, capture_output=True, text=True,
-                         timeout=900)
+                         timeout=100)
     assert out.returncode == 0, \
         f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-4000:]}"
     assert "ELASTIC_OK" in out.stdout

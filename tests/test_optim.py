@@ -72,7 +72,6 @@ def test_grad_compression_error_feedback_is_unbiased():
     # residual is bounded; accumulated estimates track the true sum
     err = float(jnp.max(jnp.abs(total_true - (total_hat + residual))))
     assert err < 1e-4
-    del cfg
 
 
 @given(st.integers(min_value=1, max_value=400),

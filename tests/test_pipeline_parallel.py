@@ -12,6 +12,6 @@ def test_pipeline_matches_sequential():
     env["PYTHONPATH"] = str(repo / "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     out = subprocess.run([sys.executable, str(script)], env=env,
-                         capture_output=True, text=True, timeout=600)
+                         capture_output=True, text=True, timeout=30)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
     assert "PP_OK" in out.stdout

@@ -121,6 +121,28 @@ def test_continuous_join_mid_flight():
         np.asarray(eng.run(eng.init_random(1), 8)), r1.state)
 
 
+def test_stop_returns_with_a_finished_task_still_registered():
+    """A bucket task that has returned, with its done callback still
+    queued, is in ``_tasks`` when stop() starts: stop() must yield to
+    the loop so the callback removes it, not spin in a gather that
+    completes without yielding."""
+    async def go():
+        svc = FractalService(_cfg())
+        await svc.start()
+        bucket = _reqs(1)[0].bucket
+        svc._running.add(bucket)
+        # nothing is pending, so the bucket coroutine returns at once
+        task = asyncio.get_running_loop().create_task(
+            svc._run_bucket(bucket))
+        svc._tasks.add(task)
+        task.add_done_callback(svc._on_task_done(bucket))
+        await asyncio.sleep(0)  # the task runs; its callback is queued
+        assert task.done() and task in svc._tasks
+        await svc.stop()
+        assert not svc._tasks and not svc._running
+    asyncio.run(go())
+
+
 # ------------------------------------------------------------- admission
 def test_queue_full_rejects_with_retry_after():
     async def go():
@@ -141,6 +163,9 @@ def test_queue_full_rejects_with_retry_after():
 
 def test_deadline_times_out_long_request():
     svc = FractalService(_cfg(max_segment_steps=1))
+    # a first request builds and compiles the bucket's engine, so that the
+    # deadline runs against steps, not against a cold build on a busy host
+    assert svc.serve(_reqs(1, steps=1, prefix="dl-warm"))[0].ok
     res = svc.serve([SimRequest(frac=FRAC, r=4, steps=100000, m=1, seed=0,
                                 deadline_s=0.2, rid="dl-0")])
     assert res[0].status == "timeout"

@@ -13,7 +13,7 @@ def _run(which: str):
     env["PYTHONPATH"] = str(repo / "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
     out = subprocess.run([sys.executable, str(script), which], env=env,
-                         capture_output=True, text=True, timeout=1800)
+                         capture_output=True, text=True, timeout=160)
     assert out.returncode == 0, \
         f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-4000:]}"
     assert "SHARDED_EQ_OK" in out.stdout
