@@ -45,8 +45,10 @@ Fault tolerance (the point):
 
 Every transition lands on the telemetry registry:
 ``serve.{admitted,rejected,completed,failed,timeouts,preempted,
-retries,restarts,recoveries,batches,segments,joins,checkpoints}``
-counters, ``serve.{latency,queue_wait,recovery}_seconds`` +
+retries,restarts,recoveries,batches,segments,joins,checkpoints,
+host_copies,host_copy_bytes}`` counters (the last two from
+``handoff.host_copy``, each inside a ``serve.host_state`` span),
+``serve.{latency,queue_wait,recovery}_seconds`` +
 ``serve.{batch_size,segment_steps}`` histograms, and
 ``serve.{queue_depth,inflight,breaker_open}`` gauges — the SLO surface
 ``benchmarks/serve_bench.py`` gates on. See DESIGN.md Section 8.
@@ -70,6 +72,7 @@ from repro.checkpoint.manager import (CheckpointCorruptError,
                                       CheckpointManager)
 from repro.runtime.fault import (FaultInjector, PreemptionHandler,
                                  Watchdog, backoff_delays)
+from repro.serving import handoff
 from repro.serving.types import (AdmissionError, CircuitBreaker,
                                  ServiceConfig, SimRequest, SimResult)
 from repro.workloads.runner import BatchedRunner
@@ -481,13 +484,15 @@ class FractalService:
 
     def _host_state(self, req: SimRequest, state) -> np.ndarray:
         """Host copy of a row's state for results, snapshots and
-        checkpoints. Distributed rows strip the engine's padding
-        blocks first: the user-facing (and checkpointed) artifact is
-        the mesh-independent dense compact state, so a checkpoint
-        written under one mesh restores under any other."""
-        if self._is_dist(req):
-            state = self._engine_of(req).to_dense(state)
-        return np.asarray(jax.device_get(state))
+        checkpoints, through ``handoff.host_copy``. Distributed rows
+        strip the engine's padding blocks before it: the user-facing
+        (and checkpointed) artifact is the mesh-independent dense
+        compact state, so a checkpoint written under one mesh restores
+        under any other."""
+        with obs.span("serve.host_state", kind=req.kind):
+            if self._is_dist(req):
+                state = self._engine_of(req).to_dense(state)
+            return handoff.host_copy(state)
 
     def _save_row(self, row: "_Row", host: np.ndarray) -> str:
         """Checkpoint one row (worker thread). Distributed rows write

@@ -12,6 +12,8 @@ The topology is described inside a fixture: only the worker that runs
 these tests loads the TPU compiler, and where it cannot be described
 the tests skip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -131,3 +133,27 @@ def test_block_step_r17_batch_fits(one_chip):
     text = _compile(fn, _state(one_chip, eng.layout, LIFE, b),
                     _table(one_chip, eng.layout))
     assert "tpu_custom_call" not in text
+
+
+#: one row of each benchmark cell's shape: Life r=17 (u8, block axis on
+#: the lanes, four cells to a word), and Gray-Scott's two channels at
+#: r=9 on the carpet in bfloat16 (its f32 rows take the plain copy; 9
+#: cells a block row leave a word straddling two rows)
+HANDOFF_ROWS = {"life-r17": (LIFE, fractals.SIERPINSKI, 17, 4, jnp.uint8),
+                "gray-scott-r9-bf16": (GRAY_SCOTT, fractals.CARPET, 9, 2,
+                                       jnp.bfloat16)}
+
+
+@pytest.mark.parametrize("case", HANDOFF_ROWS)
+def test_handoff_relayout_is_tile_linear(one_chip, case):
+    """The hand-off's relayout of one finished row compiles, fits, and
+    returns whole (8, 128) tiles of 32-bit words with no sub-word
+    packing: a layout whose bytes are in row-major order, so the copy
+    to the host needs no untiling."""
+    from repro.serving.handoff import tile_linear
+    wl, frac, r, m, dtype = HANDOFF_ROWS[case]
+    row = _state(one_chip, BlockLayout(frac, r, m), wl)
+    text = _compile(tile_linear, _sds(one_chip, row.shape, dtype))
+    out = re.search(r"entry_computation_layout=\{\(.*?\)->(.*?)\}",
+                    text).group(1)
+    assert re.fullmatch(r"u32\[\d+,128\]\{1,0:T\(8,128\)", out), out
