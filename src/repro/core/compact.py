@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import jax
@@ -37,6 +38,15 @@ Array = jnp.ndarray
 #: trailing dims out in (8, 128) tiles, so the working set of a chunk is
 #: sized in those padded bytes, not in cells
 CHUNK_WINDOW_BYTES = 128 * 1024 * 1024
+
+#: threads the host table builds run in: numpy releases the GIL over
+#: large arrays, and a table over 43 M blocks (Sierpinski r=20, m=4)
+#: takes minutes in one
+HOST_THREADS = 8
+
+#: blocks per piece of a threaded host table build: its temporaries
+#: (a few int32 arrays of this length) stay in cache
+HOST_PIECE = 1 << 18
 
 
 def window_chunk(rho: int, k: int, n_channels: int = 1,
@@ -138,45 +148,114 @@ class BlockLayout:
         return bx.reshape(-1), by.reshape(-1)
 
     @functools.cached_property
+    def _axis_origins(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(fx, fy), (cols_b, 2) and (rows_b, 2) int32: the expanded
+        block origin of block (bx, by) is ``fx[bx] + fy[by]``, in blocks.
+        lambda reads its odd levels from x and its even ones from y
+        (paper Eq. 5), so it is a sum of one term per compact axis."""
+        frac = self.frac
+        out = []
+        for n, parity in zip(reversed(self.block_dims), (1, 0)):
+            w = np.arange(n, dtype=np.int64)
+            acc = np.zeros((n, 2), np.int64)
+            for mu in range(2 - parity, self.r_b + 1, 2):
+                beta = (w // frac.k ** ((mu - 1) // 2)) % frac.k
+                acc += frac.h_lambda[beta] * frac.s ** (mu - 1)
+            out.append(acc.astype(np.int32))
+        return out[0], out[1]
+
+    @functools.cached_property
     def block_origin_expanded(self) -> np.ndarray:
-        """(n_blocks, 2) int32 cell-level expanded origin (x, y) per block."""
-        bx, by = self.block_coords
-        ex, ey = maps.lambda_map(self.frac, self.r_b,
-                                 jnp.asarray(bx), jnp.asarray(by))
-        return np.stack([np.asarray(ex), np.asarray(ey)], axis=1) * self.rho
+        """(n_blocks, 2) int32 cell-level expanded origin (x, y) per block:
+        lambda at block granularity, one sum of two per-axis terms per
+        block (``_axis_origins``)."""
+        fx, fy = (a * self.rho for a in self._axis_origins)
+        return (fy[:, None] + fx[None, :]).reshape(-1, 2)
+
+    @functools.cached_property
+    def _id_tables(self) -> Tuple[int, int, np.ndarray, np.ndarray]:
+        """(side, high side, low, high): nu at block granularity split
+        into its low ``r_b // 2`` levels and the rest. nu sums one term
+        per level, each read from that level's digits of x and y, so the
+        compact id of expanded block (x, y) is ``low[y % side, x % side]
+        + high[y // side, x // side]``, negative where a level's slot is
+        a hole; ``high`` has a border of such entries for blocks outside
+        the domain. Each table has about ``s**r_b`` entries, the
+        expanded side in blocks."""
+        frac, r_b = self.frac, self.r_b
+        wx, wy = maps.nu_weights(frac, r_b)
+        cols = self.block_dims[1]
+        bad = -(self.n_blocks + 1)
+
+        def part(levels, first):
+            side = frac.s ** levels
+            gy, gx = np.meshgrid(np.arange(side), np.arange(side),
+                                 indexing="ij")
+            ids = np.zeros((side, side), np.int64)
+            ok = np.ones((side, side), bool)
+            for j in range(levels):
+                code = frac.h_nu[(gy // frac.s ** j) % frac.s,
+                                 (gx // frac.s ** j) % frac.s]
+                ok &= code >= 0
+                mu = first + j
+                ids += np.maximum(code, 0) * (int(wy[mu]) * cols
+                                              + int(wx[mu]))
+            return np.where(ok, ids, bad)
+
+        h = r_b // 2
+        high = np.pad(part(r_b - h, h), 1, constant_values=bad)
+        dtype = np.int32 if 2 * (self.n_blocks + 1) < 2 ** 31 else np.int64
+        return (frac.s ** h, high.shape[0], part(h, 0).astype(dtype).ravel(),
+                high.astype(dtype).ravel())
+
+    def id_index(self, v: np.ndarray, axis: int):
+        """(low, high): the parts expanded block coordinate ``v`` (x for
+        ``axis`` 0, y for 1) adds to the two indices ``block_ids_from``
+        reads; the index of (x, y) is the sum of x's part and y's."""
+        side, hside, _, _ = self._id_tables
+        vh, vl = np.divmod(v, side)
+        vh = np.clip(vh, -1, hside - 2) + 1
+        if axis:
+            return vl * side, vh * hside
+        return vl, vh
+
+    def block_ids_from(self, low: np.ndarray, high: np.ndarray):
+        """Compact block ids at the indices ``id_index`` parts sum to,
+        int32, ``ghost`` where there is no block."""
+        _, _, low_t, high_t = self._id_tables
+        ids = low_t[low]
+        ids += high_t[high]
+        return np.where(ids >= 0, ids, self.ghost).astype(np.int32)
+
+    def block_ids_at(self, x: np.ndarray, y: np.ndarray) -> np.ndarray:
+        """Compact block id of the block at expanded block coordinates
+        (x, y), int32 arrays of one shape; ``ghost`` where there is none
+        (a hole, or outside the domain). Host numpy: two table reads per
+        block (``_id_tables``), not one lookup per level."""
+        (xl, xh), (yl, yh) = self.id_index(x, 0), self.id_index(y, 1)
+        return self.block_ids_from(xl + yl, xh + yh)
 
     def _map_offsets_to_table(self, offsets) -> np.ndarray:
         """(n_blocks, len(offsets)) int32 compact block id per block offset,
         built with the paper's maps at block granularity: one lambda per
         block, one nu per (block, offset); out-of-fractal blocks get the
         ``ghost`` sentinel (a zero block is appended before gathers)."""
-        frac, r_b = self.frac, self.r_b
-        bx, by = (jnp.asarray(a) for a in self.block_coords)
-        ex, ey = maps.lambda_map(frac, r_b, bx, by)
-        _, cols_b = self.block_dims
+        org = self.block_origin_expanded // self.rho
+        _ = self._id_tables  # built once, before the threads read it
         table = np.empty((self.n_blocks, len(offsets)), dtype=np.int32)
-        for d, (dx, dy) in enumerate(offsets):
-            nx, ny = ex + dx, ey + dy
-            valid = maps.is_fractal(frac, r_b, nx, ny)
-            cx, cy = maps.nu_map(frac, r_b,
-                                 jnp.clip(nx, 0, frac.side(r_b) - 1),
-                                 jnp.clip(ny, 0, frac.side(r_b) - 1))
-            ids = jnp.where(valid, cy * cols_b + cx, self.ghost)
-            table[:, d] = np.asarray(ids, dtype=np.int32)
+
+        def column(d):
+            dx, dy = offsets[d]
+            table[:, d] = self.block_ids_at(org[:, 0] + dx, org[:, 1] + dy)
+
+        with ThreadPoolExecutor(min(len(offsets), HOST_THREADS)) as pool:
+            list(pool.map(column, range(len(offsets))))
         return table
 
     @functools.cached_property
     def neighbor_table(self) -> np.ndarray:
         """(n_blocks, 8) int32 compact block id per Moore direction."""
         return self._map_offsets_to_table(MOORE_DIRS)
-
-    @functools.cached_property
-    def existence_table(self) -> np.ndarray:
-        """(n_blocks, 8) int32 {0,1}: 1 where the Moore neighbor block is a
-        real fractal block, 0 where ``neighbor_table`` holds the ghost
-        sentinel (the distributed engine's per-shard kernel operand; the
-        single-device paths derive it from the table on the device)."""
-        return (self.neighbor_table != self.ghost).astype(np.int32)
 
     # --------------------------------------------- device-side cached tables
     # One upload per layout, shared by every kernel variant and every trace:
@@ -468,46 +547,74 @@ class BlockLayout:
 
     @staticmethod
     def block_rows(flat: Array, idx: Array, n_blocks: int,
-                   rho: int) -> Array:
+                   rho: int, extra: Optional[Array] = None) -> Array:
         """Gather blocks ``idx`` (n,) from ``flat`` (..., n_blocks,
         rho*rho), the lane-dense view of a block state: ids past the
         layout (the ghost sentinel, padding rows) read as zero blocks.
-        Returns (..., n, rho, rho)."""
+        With ``extra`` (..., m, rho*rho), ids ``n_blocks + i`` name its
+        row ``i``, so ``flat`` and ``extra`` read as one buffer without
+        being concatenated, and ids past both read as zero. Returns (...,
+        n, rho, rho)."""
         rows = jnp.take(flat, jnp.minimum(idx, n_blocks - 1), axis=-2)
-        rows = rows * (idx < n_blocks)[:, None].astype(rows.dtype)
+        if extra is None:
+            rows = rows * (idx < n_blocks)[:, None].astype(rows.dtype)
+        else:
+            m = extra.shape[-2]
+            far = jnp.take(extra, jnp.clip(idx - n_blocks, 0, m - 1),
+                           axis=-2)
+            rows = jnp.where((idx < n_blocks)[:, None], rows, far)
+            rows = rows * (idx < n_blocks + m)[:, None].astype(rows.dtype)
         return rows.reshape(rows.shape[:-1] + (rho, rho))
 
-    def map_chunks(self, state: Array, table: Array, chunk: int, fn):
+    def map_chunks(self, state: Array, table: Array, chunk: int, fn,
+                   ids: Optional[Array] = None,
+                   extra: Optional[Array] = None):
         """Apply a halo-reading block update chunk by chunk.
 
-        ``state`` (..., n_blocks, rho, rho); ``table`` (n_blocks, T)
-        neighbor ids per block (ghost = ``self.ghost``); ``fn(center,
-        nbr, rows)`` maps one chunk — ``center`` (..., c, rho, rho),
-        ``nbr(j)`` the blocks named by column ``j`` of the chunk's table
-        rows ``rows`` (c, T) — to its (..., c, rho, rho) update.
+        ``state`` (..., n, rho, rho), ``n`` the layout's blocks or a
+        shard's; ``table`` (R, T) neighbor ids per updated block (ids
+        past the state's blocks read as zero, or from ``extra`` as
+        ``block_rows`` says); ``fn(center, nbr, rows)`` maps one chunk —
+        ``center`` (..., c, rho, rho), ``nbr(j)`` the blocks named by
+        column ``j`` of the chunk's table rows ``rows`` (c, T) — to its
+        (..., c, rho, rho) update. Table row ``i`` updates block ``i``,
+        or block ``ids[i]`` where ``ids`` (R,) is given. Returns the R
+        updated blocks.
 
-        The chunks run in a ``fori_loop`` over a lane-dense (...,
-        n_blocks, rho*rho) view, so every temporary with small trailing
-        dims (halo strips, windows, which a TPU pads to (8, 128) tiles)
-        is chunk-sized: the working set stays bounded however many
-        blocks the layout has. One chunk when ``chunk >= n_blocks``."""
-        nb, rho = self.n_blocks, self.rho
+        The chunks run in a ``fori_loop`` over a lane-dense (..., n,
+        rho*rho) view, so every temporary with small trailing dims (halo
+        strips, windows, which a TPU pads to (8, 128) tiles) is
+        chunk-sized: the working set stays bounded however many blocks
+        the layout has. One chunk when ``chunk >= R``."""
+        nb, rho = state.shape[-3], self.rho
         lead = state.shape[:-3]
         flat = state.reshape(lead + (nb, rho * rho))
-        if chunk >= nb:
-            return fn(state, lambda j: self.block_rows(
-                flat, table[:, j], nb, rho), table)
-        n_chunks = -(-nb // chunk)
+        n_rows = table.shape[0]
+
+        def nbr_of(rows):
+            return lambda j: self.block_rows(flat, rows[:, j], nb, rho,
+                                             extra)
+
+        if chunk >= n_rows:
+            center = state if ids is None else self.block_rows(
+                flat, ids, nb, rho)
+            return fn(center, nbr_of(table), table)
+        n_chunks = -(-n_rows // chunk)
+        pad = n_chunks * chunk - n_rows
         table = jnp.concatenate(
-            [table, jnp.full((n_chunks * chunk - nb, table.shape[1]),
-                             self.ghost, table.dtype)], axis=0)
+            [table, jnp.full((pad, table.shape[1]), self.ghost,
+                             table.dtype)], axis=0)
+        if ids is not None:
+            ids = jnp.concatenate([ids, jnp.full((pad,), nb, ids.dtype)])
 
         def body(c, out):
             base = c * chunk
             rows = jax.lax.dynamic_slice_in_dim(table, base, chunk, 0)
-            ids = base + jnp.arange(chunk, dtype=table.dtype)
-            res = fn(self.block_rows(flat, ids, nb, rho),
-                     lambda j: self.block_rows(flat, rows[:, j], nb, rho),
+            if ids is None:
+                cid = base + jnp.arange(chunk, dtype=table.dtype)
+            else:
+                cid = jax.lax.dynamic_slice_in_dim(ids, base, chunk, 0)
+            res = fn(self.block_rows(flat, cid, nb, rho), nbr_of(rows),
                      rows)
             return jax.lax.dynamic_update_slice_in_dim(
                 out, res.reshape(lead + (chunk, rho * rho)), base,
@@ -515,7 +622,7 @@ class BlockLayout:
 
         out = jnp.zeros(lead + (n_chunks * chunk, rho * rho), state.dtype)
         out = jax.lax.fori_loop(0, n_chunks, body, out)
-        return out[..., :nb, :].reshape(state.shape)
+        return out[..., :n_rows, :].reshape(lead + (n_rows, rho, rho))
 
     def pad_with_halo_k(self, state_b: Array, k: int, table=None) -> Array:
         """Assemble (n_blocks, rho+2k, rho+2k) tiles with depth-``k`` halos.
@@ -536,14 +643,17 @@ class BlockLayout:
             state_b, lambda oi: self.block_rows(flat, table[:, oi], nb, rho),
             k)
 
-    def halo_gate(self, k: int, table) -> Array:
+    def halo_gate(self, k: int, table, ghost: Optional[int] = None
+                  ) -> Array:
         """(n, rho+2k, rho+2k) uint8 occupancy of each block's depth-``k``
         window — ``halo_mask(k)`` rebuilt in-trace from the offset table
-        (``table`` (n, len(halo_offsets(k))), ghost = ``self.ghost``):
-        the periodic ``window_mask`` with each ghost neighbor's region
-        zeroed. Built on the device, so no (n_blocks, w, w) table is
-        ever uploaded or baked into a compiled program."""
-        exist = (table != self.ghost).astype(jnp.uint8)
+        (``table`` (n, len(halo_offsets(k))), ghost = ``ghost``, by
+        default ``self.ghost``): the periodic ``window_mask`` with each
+        ghost neighbor's region zeroed. Built on the device, so no
+        (n_blocks, w, w) table is ever uploaded or baked into a compiled
+        program."""
+        ghost = self.ghost if ghost is None else ghost
+        exist = (table != ghost).astype(jnp.uint8)
         n = table.shape[0]
 
         def piece(oi, dst, src):
@@ -591,21 +701,22 @@ def _balanced_contiguous_partition(counts: np.ndarray,
         raise ValueError(f"cannot split {n} rows into {n_groups} "
                          "nonempty groups")
 
+    cum = np.concatenate([[0], np.cumsum(counts)])
+
     def bounds_for(cap):
         """Greedy fill under ``cap``, always leaving enough rows for the
-        remaining groups to stay nonempty; None when infeasible."""
-        out, start, acc = [], 0, 0
-        g = n_groups
-        for i, c in enumerate(counts):
-            must_cut = (n - i) == (g - 1)  # later groups need the rest
-            if i > start and (acc + c > cap or must_cut):
-                out.append((start, i))
-                start, acc, g = i, 0, g - 1
-            acc += c
-            if acc > cap and i > start:
-                return None
+        remaining groups to stay nonempty; None when infeasible. Each
+        group ends at the last row its capacity reaches
+        (``searchsorted`` on the running sum)."""
+        out, start = [], 0
+        for g in range(n_groups, 1, -1):
+            end = int(np.searchsorted(cum, cum[start] + cap,
+                                      side="right")) - 1
+            end = min(max(end, start + 1), n - (g - 1))
+            out.append((start, end))
+            start = end
         out.append((start, n))
-        return out if len(out) == n_groups and acc <= cap else None
+        return out if cum[n] - cum[start] <= cap else None
 
     lo, hi = int(counts.max()), int(counts.sum())
     while lo < hi:
@@ -658,11 +769,13 @@ class StripDecomposition:
       ghost row, [nbl+1, nbl+1+ms_next) strips received from the prev
       neighbor (its send_next buffer), then ms_prev slots received
       from the next neighbor (its send_prev buffer);
-    * ``interior_idx`` / ``boundary_idx`` — (n_shards, max_interior/
-      boundary) local indices partitioning each shard's slots into
-      blocks whose depth-k halo is fully shard-local (interior: compute
-      overlaps the in-flight exchange) and blocks that must wait for a
-      neighbor strip (boundary), padded with the same sentinel.
+    * ``edge_rows`` — (n_shards, 2) blocks in each shard's first and
+      last expanded row. Only those rows border another shard, and
+      row-major order puts them first and last among the shard's
+      slots, so every block with a remote neighbor (a *boundary* block:
+      it waits for the exchange, every other block's update overlaps
+      it) lies in the head ``[0, first)`` or the tail ``[n - last, n)``
+      of its shard's ``n`` real slots.
     """
 
     layout: BlockLayout
@@ -681,54 +794,102 @@ class StripDecomposition:
     def _build(self) -> None:
         layout = self.layout
         nb, rho = layout.n_blocks, layout.rho
-        org = layout.block_origin_expanded
-        ex, ey = org[:, 0] // rho, org[:, 1] // rho
-        order = np.lexsort((ex, ey)).astype(np.int32)
-        rows, counts = np.unique(ey, return_counts=True)
+        org = layout.block_origin_expanded // rho
+        ex, ey = org[:, 0], org[:, 1]
+        side = layout.frac.s ** layout.r_b
+        # row-major expanded order; 16-bit keys take numpy's radix sort
+        key = np.uint16 if side <= 1 << 16 else np.int32
+        order = np.lexsort((ex.astype(key), ey.astype(key))).astype(np.int32)
+        per_row = np.bincount(ey, minlength=side)
+        rows = np.flatnonzero(per_row)
+        counts = per_row[rows]
         if len(rows) < self.n_shards:
             self._set(valid=False, nb_local=0, nb_padded=0, perm=None,
                       shard_of=None, local_of=None)
             return
         bounds = _balanced_contiguous_partition(counts, self.n_shards)
-        # vectorized over blocks: the builds stay O(nb log nb) numpy at
-        # paper scale (nb = 3^13 at Sierpinski r=17, m=4)
-        row_shard = np.empty(len(rows), np.int32)
+        # each shard holds a contiguous range of the row-major order:
+        # ranks [starts[s], starts[s + 1])
+        starts = np.concatenate([[0], np.cumsum(
+            [counts[a:b].sum() for a, b in bounds])]).astype(np.int64)
+        sizes = np.diff(starts)
+        nbl = int(sizes.max())
+        row_shard = np.zeros(side, np.int32)
         for sh, (a, b) in enumerate(bounds):
-            row_shard[a:b] = sh
-        shard_of = row_shard[np.searchsorted(rows, ey)].astype(np.int32)
-        nbl = int(max(counts[a:b].sum() for a, b in bounds))
-        # row-major within each strip: rank of each block in its shard
-        ordered_shards = shard_of[order]
-        starts = np.searchsorted(ordered_shards,
-                                 np.arange(self.n_shards))
-        local_of = np.empty(nb, np.int32)
-        local_of[order] = (np.arange(nb) - starts[ordered_shards]
-                           ).astype(np.int32)
-        perm = np.full(self.n_shards * nbl, -1, np.int32)
-        perm[shard_of * nbl + local_of] = np.arange(nb, dtype=np.int32)
+            row_shard[rows[a]:rows[b - 1] + 1] = sh
+        shard_of = row_shard[ey]
+        rank = np.empty(nb, np.int32)
+        rank[order] = np.arange(nb, dtype=np.int32)
+        local_of = (rank - starts[shard_of]).astype(np.int32)
+        perm = np.full((self.n_shards, nbl), -1, np.int32)
+        for sh in range(self.n_shards):
+            perm[sh, :sizes[sh]] = order[starts[sh]:starts[sh + 1]]
         self._set(valid=True, nb_local=nbl,
-                  nb_padded=self.n_shards * nbl, perm=perm,
-                  shard_of=shard_of, local_of=local_of)
-        self._build_routing()
+                  nb_padded=self.n_shards * nbl, perm=perm.reshape(-1),
+                  shard_of=shard_of, local_of=local_of,
+                  edge_rows=np.array([(counts[a], counts[b - 1])
+                                      for a, b in bounds], np.int64))
+        self._build_routing(ex[order], ey[order], rank, starts)
 
-    def _build_routing(self) -> None:
+    def _build_routing(self, ex, ey, rank, starts) -> None:
+        """The routing tables, from the blocks' expanded block
+        coordinates (``ex``, ``ey``) in row-major order, each block's
+        rank in that order (``rank``, by compact id) and each shard's
+        first rank (``starts``). Per Moore direction: the neighbor's
+        compact id (``BlockLayout.block_ids_from``, the three x and
+        three y offsets' index parts computed once), then its rank,
+        which is its shard and its local slot. The work is split into
+        pieces of ``HOST_PIECE`` blocks of one shard, run in
+        ``HOST_THREADS`` threads."""
         layout, nbl, ns = self.layout, self.nb_local, self.n_shards
-        table_g = layout.neighbor_table  # (nb, 8) compact block ids
-        ghost = layout.ghost
-        shard_of, local_of = self.shard_of, self.local_of
-        g, d = np.nonzero(table_g != ghost)  # every real (block, dir)
-        ng = table_g[g, d]
-        s, so = shard_of[g], shard_of[ng]
+        _ = layout._id_tables  # built once, before the threads read it
+        rank_g = np.append(rank, np.int32(-1))  # the ghost has none
+        table = np.empty((ns, nbl, 8), np.int32)
+        for sh in range(ns):
+            table[sh, starts[sh + 1] - starts[sh]:] = nbl
+
+        def piece(task):
+            """Fill every column of ranks [a, b) of shard ``sh`` for
+            same-shard neighbors; return (s, li, d, so, lo) of the
+            cross-shard ones."""
+            sh, a, b = task
+            lo0, n = starts[sh], starts[sh + 1] - starts[sh]
+            xs = {dx: layout.id_index(ex[a:b] + dx, 0) for dx in (-1, 0, 1)}
+            ys = {dy: layout.id_index(ey[a:b] + dy, 1) for dy in (-1, 0, 1)}
+            out = []
+            cols = np.empty((b - a, 8), np.int32)
+            for d, (dx, dy) in enumerate(MOORE_DIRS):
+                (xl, xh), (yl, yh) = xs[dx], ys[dy]
+                nrank = rank_g[layout.block_ids_from(xl + yl, xh + yh)]
+                loc = nrank - lo0
+                same = (loc >= 0) & (loc < n)
+                cols[:, d] = np.where(same, loc, nbl)
+                li = np.flatnonzero(~same & (nrank >= 0))
+                far = nrank[li]
+                so = (np.searchsorted(starts, far, side="right") - 1
+                      ).astype(np.int32)
+                out.append((np.full(li.size, sh, np.int32),
+                            (li + (a - lo0)).astype(np.int32),
+                            np.full(li.size, d), so,
+                            (far - starts[so]).astype(np.int32)))
+            table[sh, a - lo0:b - lo0] = cols
+            return [np.concatenate(c) for c in zip(*out)]
+
+        tasks = [(sh, a, min(a + HOST_PIECE, starts[sh + 1]))
+                 for sh in range(ns)
+                 for a in range(starts[sh], starts[sh + 1], HOST_PIECE)]
+        with ThreadPoolExecutor(HOST_THREADS) as pool:
+            cross = list(pool.map(piece, tasks))
+        s, li, d, so, lo = (np.concatenate(c) for c in zip(*cross))
         delta = so - s
         bad = np.flatnonzero(np.abs(delta) > 1)
         if bad.size:  # the row-strip invariant
             i = bad[0]
             raise AssertionError(
-                f"strip decomposition broke: blocks {g[i]}->{ng[i]} "
-                f"span shards {s[i]}->{so[i]}")
+                f"strip decomposition broke: shard {s[i]} slot {li[i]} "
+                f"has a neighbor on shard {so[i]}")
         # ng's strip travels from its shard so back to s, i.e. shard s+1
         # sends to its PREV neighbor and shard s-1 to its NEXT one
-        lo = local_of[ng]
 
         def needed(sign):
             sel = delta == sign
@@ -753,40 +914,19 @@ class StripDecomposition:
                 out[sel] = np.searchsorted(lists[sh], ids[sel])
             return out
 
-        # per-shard halo table in combined strip coordinates
-        table = np.full((ns, nbl, 8), nbl, np.int32)
-        li = local_of[g]
-        same, from_prev = delta == 0, delta == -1
-        from_next = delta == 1
-        table[s[same], li[same], d[same]] = lo[same]
         # recv-from-prev slab = prev shard's send_next buffer (width
         # ms_next); recv-from-next slab = next shard's send_prev buffer
+        from_prev, from_next = delta == -1, delta == 1
         table[s[from_prev], li[from_prev], d[from_prev]] = (
             nbl + 1 + slot(send_next, so[from_prev], lo[from_prev]))
         table[s[from_next], li[from_next], d[from_next]] = (
             nbl + 1 + ms_next + slot(send_prev, so[from_next],
                                      lo[from_next]))
-        remote = np.zeros((ns, nbl), bool)
-        remote[s[~same], li[~same]] = True
-
-        # interior/boundary partition of every local slot (dead padding
-        # slots are interior: they compute to zero without any strip)
-        interior = [np.flatnonzero(~remote[sh]) for sh in range(ns)]
-        boundary = [np.flatnonzero(remote[sh]) for sh in range(ns)]
-        mi = max(1, max(len(x) for x in interior))
-        mb = max(1, max(len(x) for x in boundary))
         self._set(
             ms_prev=ms_prev, ms_next=ms_next,
             send_prev_idx=pad(send_prev, ms_prev),
             send_next_idx=pad(send_next, ms_next),
             table=table,
-            interior_idx=pad(interior, mi),
-            boundary_idx=pad(boundary, mb),
-            n_interior=np.array([len(x) for x in interior], np.int32),
-            n_boundary=np.array([len(x) for x in boundary], np.int32),
-            real_sends=sum(1 for sh in range(ns - 1)
-                           if len(send_next[sh])) +
-            sum(1 for sh in range(1, ns) if len(send_prev[sh])),
         )
 
     # ------------------------------------------------------- exchange ops

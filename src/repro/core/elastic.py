@@ -31,9 +31,8 @@ demo-shaped to production posture (DESIGN.md Section 9):
   (:class:`~repro.runtime.fault.DeviceLostError`) triggers the elastic
   path: drop the lost device, rebuild the engine on a smaller mesh
   (8 -> 4 devices), which re-derives every per-shard static operand
-  (``_shard_operands``: halo masks, ghost-remapped offset tables,
-  existence rows, the padded block count — all keyed off the new shard
-  count), restore the newest intact checkpoint onto the new sharding
+  (``operands()``: the ghost-remapped step table and routing rows, the
+  padded block count — all keyed off the new shard count), restore the newest intact checkpoint onto the new sharding
   (``from_dense`` re-pads and re-places), and continue;
 * **degraded-mode** — the run finishes on the shrunken mesh
   (``stats.degraded``), still bit-exact: padding blocks are

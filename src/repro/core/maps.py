@@ -90,8 +90,11 @@ def is_fractal_scalar(frac: NBBFractal, r: int, ex: int, ey: int) -> bool:
 # ======================================================================
 def lambda_map(frac: NBBFractal, r: int, cx: Array, cy: Array
                ) -> Tuple[Array, Array]:
-    """Vectorised lambda(w). cx/cy: int32 arrays of any (matching) shape."""
-    h = jnp.asarray(frac.h_lambda)  # (k, 2)
+    """Vectorised lambda(w). cx/cy: int32 arrays of any (matching) shape.
+    The slot of replica ``beta`` is looked up per axis (two (k,) tables,
+    not one (k, 2) table): a (..., 2) lookup result has a minor axis of
+    2, which a TPU pads to 128 lanes."""
+    hx, hy = (jnp.asarray(frac.h_lambda[:, a]) for a in (0, 1))
     cx = cx.astype(jnp.int32)
     cy = cy.astype(jnp.int32)
     ex = jnp.zeros_like(cx)
@@ -99,11 +102,23 @@ def lambda_map(frac: NBBFractal, r: int, cx: Array, cy: Array
     for mu in range(1, r + 1):
         w = cx if (mu % 2 == 1) else cy
         beta = (w // (frac.k ** ((mu - 1) // 2))) % frac.k
-        tau = h[beta]  # (..., 2)
         scale = frac.s ** (mu - 1)
-        ex = ex + tau[..., 0] * scale
-        ey = ey + tau[..., 1] * scale
+        ex = ex + hx[beta] * scale
+        ey = ey + hy[beta] * scale
     return ex, ey
+
+
+def _nu_levels(frac: NBBFractal, r: int, ex: Array, ey: Array):
+    """The per-level replica codes H_nu[theta_mu], mu = 1..r, one array
+    shaped like ``ex`` each (holes -1). Consumed level by level, so that
+    no (..., r) array of all levels is held: its minor axis of r would
+    be padded to 128 lanes on a TPU."""
+    hn = jnp.asarray(frac.h_nu)  # (s, s) indexed [y, x]
+    ex = ex.astype(jnp.int32)
+    ey = ey.astype(jnp.int32)
+    for mu in range(1, r + 1):
+        scale = frac.s ** (mu - 1)
+        yield hn[(ey // scale) % frac.s, (ex // scale) % frac.s]
 
 
 def _nu_codes(frac: NBBFractal, r: int, ex: Array, ey: Array) -> Array:
@@ -111,36 +126,40 @@ def _nu_codes(frac: NBBFractal, r: int, ex: Array, ey: Array) -> Array:
 
     Holes produce -1 (useful for membership tests); nu_map clamps to 0.
     """
-    hn = jnp.asarray(frac.h_nu)  # (s, s) indexed [y, x]
-    ex = ex.astype(jnp.int32)
-    ey = ey.astype(jnp.int32)
-    codes = []
-    for mu in range(1, r + 1):
-        scale = frac.s ** (mu - 1)
-        tx = (ex // scale) % frac.s
-        ty = (ey // scale) % frac.s
-        codes.append(hn[ty, tx])
-    return jnp.stack(codes, axis=-1)
+    return jnp.stack(list(_nu_levels(frac, r, ex, ey)), axis=-1)
+
+
+def _nu_fold(frac: NBBFractal, r: int, ex: Array, ey: Array):
+    """(cx, cy, all levels occupied) from one pass over the levels."""
+    wx, wy = nu_weights(frac, r)
+    cx = cy = jnp.zeros(jnp.shape(ex), jnp.int32)
+    occupied = jnp.ones(jnp.shape(ex), bool)
+    for mu, code in enumerate(_nu_levels(frac, r, ex, ey)):
+        occupied = occupied & (code >= 0)
+        code = jnp.maximum(code, 0)
+        cx = cx + code * int(wx[mu])
+        cy = cy + code * int(wy[mu])
+    return cx, cy, occupied
 
 
 def nu_map(frac: NBBFractal, r: int, ex: Array, ey: Array
            ) -> Tuple[Array, Array]:
     """Vectorised nu(w). ex/ey: int32 arrays of any (matching) shape."""
-    codes = jnp.maximum(_nu_codes(frac, r, ex, ey), 0)  # (..., r)
-    wx, wy = nu_weights(frac, r)
-    cx = jnp.sum(codes * wx.astype(jnp.int32), axis=-1)
-    cy = jnp.sum(codes * wy.astype(jnp.int32), axis=-1)
-    return cx.astype(jnp.int32), cy.astype(jnp.int32)
+    cx, cy, _ = _nu_fold(frac, r, ex, ey)
+    return cx, cy
+
+
+def _clipped(frac: NBBFractal, r: int, ex: Array, ey: Array):
+    """(in bounds, ex clipped, ey clipped) against the level-r side."""
+    n = frac.s ** r
+    in_bounds = (ex >= 0) & (ex < n) & (ey >= 0) & (ey < n)
+    return in_bounds, jnp.clip(ex, 0, n - 1), jnp.clip(ey, 0, n - 1)
 
 
 def is_fractal(frac: NBBFractal, r: int, ex: Array, ey: Array) -> Array:
     """Vectorised fractal-membership test for expanded coordinates."""
-    n = frac.s ** r
-    in_bounds = (ex >= 0) & (ex < n) & (ey >= 0) & (ey < n)
-    exc = jnp.clip(ex, 0, n - 1)
-    eyc = jnp.clip(ey, 0, n - 1)
-    codes = _nu_codes(frac, r, exc, eyc)
-    return in_bounds & jnp.all(codes >= 0, axis=-1)
+    in_bounds, exc, eyc = _clipped(frac, r, ex, ey)
+    return in_bounds & _nu_fold(frac, r, exc, eyc)[2]
 
 
 def nu_with_membership(frac: NBBFractal, r: int, ex: Array, ey: Array
@@ -148,17 +167,9 @@ def nu_with_membership(frac: NBBFractal, r: int, ex: Array, ey: Array
     """Fused nu(w) + membership: one digit pass serves both (the stencil
     inner loop needs both per neighbor, so computing codes twice would
     double the map cost). Returns (cx, cy, valid)."""
-    n = frac.s ** r
-    in_bounds = (ex >= 0) & (ex < n) & (ey >= 0) & (ey < n)
-    exc = jnp.clip(ex, 0, n - 1)
-    eyc = jnp.clip(ey, 0, n - 1)
-    codes = _nu_codes(frac, r, exc, eyc)  # (..., r)
-    valid = in_bounds & jnp.all(codes >= 0, axis=-1)
-    codes = jnp.maximum(codes, 0)
-    wx, wy = nu_weights(frac, r)
-    cx = jnp.sum(codes * wx.astype(jnp.int32), axis=-1)
-    cy = jnp.sum(codes * wy.astype(jnp.int32), axis=-1)
-    return cx.astype(jnp.int32), cy.astype(jnp.int32), valid
+    in_bounds, exc, eyc = _clipped(frac, r, ex, ey)
+    cx, cy, occupied = _nu_fold(frac, r, exc, eyc)
+    return cx, cy, in_bounds & occupied
 
 
 # ======================================================================

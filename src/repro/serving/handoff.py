@@ -33,6 +33,14 @@ LANES = 128
 #: 32-bit words in one (8, 128) tile
 TILE_WORDS = 8 * LANES
 
+#: the most of a state ``host_pieces`` relays out at once: the relayout's
+#: temporaries take about three times its input (described-v5e compiles:
+#: 1.22 GB for a 408 MB Life row, 8.27 GB for a 2.76 GB shard of the
+#: r=20 domain), which a chip holding the domain cannot spare, and its
+#: compile time grows with its size (5.6 s at 256 MiB of u8 blocks, 22 s
+#: at 1 GiB, on a described v5e)
+PIECE_BYTES = 1 << 28
+
 
 def _row_major(x: jax.Array) -> jax.Array:
     """``x.reshape(-1)``, written as a transpose of its largest axis back
@@ -82,22 +90,57 @@ def _from_words(words: np.ndarray, shape, dtype) -> np.ndarray:
     return words.reshape(-1).view(np.uint8)[:n].view(dtype).reshape(shape)
 
 
+def start_host_copy(state: jax.Array):
+    """Start copying a device array to the host, bit for bit, and return
+    a function that waits for the copy and returns the host array.
+    Elements narrower than a word, which the chip packs several to a
+    word, go through ``tile_linear`` (path ``flat``); 32-bit and wider
+    ones are copied as they are (path ``plain``): the host untiles
+    32-bit tiles at the speed it copies, so relaying them out first
+    gains nothing (a v5e moved a 1.36 GB ``f32`` row in 0.56-0.63 s
+    either way, a ``bf16`` one in 0.36-0.40 s flat against 1.05-1.08 s
+    plain). Counts ``serve.host_copies{path}`` and
+    ``serve.host_copy_bytes{path}`` when the copy lands."""
+    flat = state.dtype.itemsize < 4
+    dev = tile_linear(state) if flat else state
+    dev.copy_to_host_async()
+
+    def finish() -> np.ndarray:
+        host = np.asarray(jax.device_get(dev))
+        if flat:
+            host = _from_words(host, state.shape, state.dtype)
+        path = "flat" if flat else "plain"
+        obs.inc("serve.host_copies", path=path)
+        obs.inc("serve.host_copy_bytes", host.nbytes, path=path)
+        return host
+
+    return finish
+
+
 def host_copy(state: jax.Array) -> np.ndarray:
-    """Host copy of a device array, bit for bit. Elements narrower than
-    a word, which the chip packs several to a word, go through
-    ``tile_linear`` (path ``flat``); 32-bit and wider ones are copied
-    as they are (path ``plain``): the host untiles 32-bit tiles at the
-    speed it copies, so relaying them out first gains nothing (a v5e
-    moved a 1.36 GB ``f32`` row in 0.56-0.63 s either way, a ``bf16``
-    one in 0.36-0.40 s flat against 1.05-1.08 s plain). Counts
-    ``serve.host_copies{path}`` and ``serve.host_copy_bytes{path}``."""
-    if state.dtype.itemsize < 4:
-        path = "flat"
-        words = np.asarray(jax.device_get(tile_linear(state)))
-        host = _from_words(words, state.shape, state.dtype)
-    else:
-        path = "plain"
-        host = np.asarray(jax.device_get(state))
-    obs.inc("serve.host_copies", path=path)
-    obs.inc("serve.host_copy_bytes", host.nbytes, path=path)
-    return host
+    """Host copy of a device array (``start_host_copy``, waited for)."""
+    return start_host_copy(state)()
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _piece(x: jax.Array, start, size: int, axis: int) -> jax.Array:
+    return lax.dynamic_slice_in_dim(x, start, size, axis)
+
+
+def host_pieces(state: jax.Array, axis: int):
+    """Copy ``state`` to the host in pieces along ``axis``, each at most
+    ``PIECE_BYTES`` and all of one size (the last one overlaps the one
+    before it), the next piece's copy started before a piece is
+    yielded. Yields ``(start, stop, host piece)``."""
+    n = state.shape[axis]
+    size = max(1, min(n, PIECE_BYTES * n // max(state.nbytes, 1)))
+    starts = list(range(0, n - size, size)) + [n - size]
+    pending = None
+    for start in starts:
+        piece = state if size == n else _piece(state, start, size,
+                                               axis % state.ndim)
+        ahead = (start, start_host_copy(piece))
+        if pending is not None:
+            yield pending[0], pending[0] + size, pending[1]()
+        pending = ahead
+    yield pending[0], pending[0] + size, pending[1]()

@@ -203,7 +203,7 @@ class FractalService:
         if self._queued >= cfg.max_queue:
             obs.inc("serve.rejected", reason="queue_full")
             raise AdmissionError("queue_full", cfg.retry_after_s)
-        obs.inc("serve.admitted", kind=req.kind)
+        obs.inc("serve.admitted", kind=req.bucket.kind)
 
     async def submit(self, req: SimRequest) -> SimResult:
         """Admit + enqueue ``req`` and await its result. Raises
@@ -367,25 +367,19 @@ class FractalService:
 
             # a batch shape this bucket has not launched yet is compiled
             # ahead of its launch, outside the hang clock: compilation is
-            # set-up, however long it takes, and never reads as a hang.
-            # Kinds that compile inside their own run (dist-*) get the
-            # compile grace on that first launch instead.
-            cold = len(rows) not in warm
-
+            # set-up, however long it takes, and never reads as a hang
             def compile_ahead(states=states):
                 return self.runner.compile_run(
                     bucket, states, donate=True, frac=rep.frac,
                     workload=rep.workload)
 
             try:
-                if cold and await run_in(self._executor,
-                                         compile_ahead) is not None:
-                    cold = False
-                timeout = (max(cfg.hang_threshold_s, cfg.compile_grace_s)
-                           if cold else cfg.hang_threshold_s)
+                if len(rows) not in warm:
+                    await run_in(self._executor, compile_ahead)
                 self.watchdog.start_step()
                 out = await asyncio.wait_for(
-                    run_in(self._executor, work), timeout=timeout)
+                    run_in(self._executor, work),
+                    timeout=cfg.hang_threshold_s)
             except asyncio.TimeoutError:
                 # hang: abandon the stuck thread, kill + restart the
                 # compiled engine, recover the batch from checkpoints
@@ -480,18 +474,19 @@ class FractalService:
 
     @staticmethod
     def _is_dist(req: SimRequest) -> bool:
-        return req.kind.startswith("dist-")
+        return req.bucket.is_dist
 
     def _host_state(self, req: SimRequest, state) -> np.ndarray:
         """Host copy of a row's state for results, snapshots and
-        checkpoints, through ``handoff.host_copy``. Distributed rows
-        strip the engine's padding blocks before it: the user-facing
-        (and checkpointed) artifact is the mesh-independent dense
-        compact state, so a checkpoint written under one mesh restores
-        under any other."""
-        with obs.span("serve.host_state", kind=req.kind):
+        checkpoints, through ``handoff.host_copy``. A distributed row is
+        copied shard by shard and its real blocks put in compact order
+        on the host (``host_dense``): the user-facing (and checkpointed)
+        artifact is the mesh-independent dense compact state, so a
+        checkpoint written under one mesh restores under any other, and
+        no device gathers the whole state."""
+        with obs.span("serve.host_state", kind=req.bucket.kind):
             if self._is_dist(req):
-                state = self._engine_of(req).to_dense(state)
+                return self._engine_of(req).host_dense(state)
             return handoff.host_copy(state)
 
     def _save_row(self, row: "_Row", host: np.ndarray) -> str:
@@ -578,11 +573,12 @@ class FractalService:
             steps_done=row.done, latency_s=now - p.t_submit,
             queue_wait_s=row.t_start - p.t_submit,
             retries=p.retries, recoveries=p.recoveries, error=error)
-        self._count_outcome(status, p.req.kind)
+        kind = p.req.bucket.kind
+        self._count_outcome(status, kind)
         obs.observe("serve.latency_seconds", res.latency_s,
-                    kind=p.req.kind, status=status)
+                    kind=kind, status=status)
         obs.observe("serve.queue_wait_seconds", res.queue_wait_s,
-                    kind=p.req.kind)
+                    kind=kind)
         self._set_result(p.future, res)
 
     _OUTCOMES = {"ok": "serve.completed", "failed": "serve.failed",
@@ -614,7 +610,7 @@ class FractalService:
         while q:
             p = q.popleft()
             self._queued -= 1
-            self._count_outcome(status, p.req.kind)
+            self._count_outcome(status, p.req.bucket.kind)
             self._set_result(p.future, SimResult(
                 rid=p.req.rid, status=status, steps_done=0,
                 latency_s=time.monotonic() - p.t_submit, error=error,

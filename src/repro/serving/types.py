@@ -40,7 +40,8 @@ class SimRequest:
     steps: int
     workload: StencilWorkload = LIFE
     m: int = 0
-    kind: str = "block"
+    #: engine kind; ``'auto'`` lets the program choose (``bucket``)
+    kind: str = "auto"
     k: Optional[int] = None            # fusion depth (None = heuristic)
     seed: int = 0
     snapshot_every: int = 0            # 0 = final state only
@@ -63,15 +64,35 @@ class SimRequest:
         resolved default, etc. all collapse). Computed once per request
         (the tuning-table consult and its ``engine.tune.*`` telemetry
         fire on first access); mutating the identity fields afterwards
-        does not re-bucket."""
+        does not re-bucket.
+
+        Kind ``'auto'`` resolves here, from what the process can see
+        (``tuning.spec.auto_kind``): ``'dist-block'`` over every local
+        device (``mesh_shape=(n,)``) when one row's working set on the
+        block path does not fit one device and there are several,
+        ``'block'`` otherwise."""
         b = self.__dict__.get("_bucket")
         if b is None:
-            from repro.tuning.spec import EngineSpec
-            b = EngineSpec.from_args(
-                self.kind, self.frac, self.r, self.m, self.workload,
-                fusion_k=self.k).normalize()
+            from repro.tuning import spec
+            kind, mesh = self.kind, None
+            if kind == "auto":
+                limit, n = spec.device_capacity()
+                kind = spec.auto_kind(self.state_bytes, limit, n)
+                mesh = (n,) if kind == "dist-block" else None
+            b = spec.EngineSpec.from_args(
+                kind, self.frac, self.r, self.m, self.workload,
+                fusion_k=self.k, mesh=mesh).normalize()
             self.__dict__["_bucket"] = b
         return b
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of one state of this request in block form: channels x
+        blocks x rho^2 cells x the workload's element size."""
+        import numpy as np
+        rho = self.frac.s ** self.m
+        return (self.workload.n_channels * self.frac.volume(self.r - self.m)
+                * rho * rho * np.dtype(self.workload.dtype).itemsize)
 
 
 @dataclasses.dataclass
@@ -138,13 +159,9 @@ class ServiceConfig:
     backoff_base_s: float = 0.02
     backoff_cap_s: float = 0.5
     backoff_seed: int = 0
-    # ---- watchdog (hang detection on one segment's wall time)
+    # ---- watchdog (hang detection on one segment's wall time; every
+    #      batch shape is compiled ahead, outside this clock)
     hang_threshold_s: float = 10.0
-    #: wall-time allowance of a segment whose batch shape has not run
-    #: before and could not be compiled ahead of its launch (the dist-*
-    #: kinds compile inside their own run); every other kind compiles
-    #: ahead, outside the hang clock, whatever the compile takes
-    compile_grace_s: float = 60.0
     # ---- circuit breaker
     breaker_threshold: int = 5     # consecutive failures to open
     breaker_cooldown_s: float = 1.0

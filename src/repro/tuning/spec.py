@@ -63,6 +63,39 @@ _DEFAULT_TABLE = object()
 FracId = Union[str, Tuple[Tuple[int, ...], ...]]
 
 
+#: working set of one row on the block path, in multiples of the row's
+#: state bytes. Described-v5e compiles of the block path's batched run
+#: (one row, donated, k=3; arguments + output + temporaries - aliased,
+#: the neighbor table included) take 3.25 x the row's state for Life on
+#: the Sierpinski triangle at r=17 and at r=19 (m=4), and 2.63 x for
+#: Gray-Scott on the carpet at r=9 (m=2); the service keeps the row's
+#: own state resident beside the run, one more
+BLOCK_ROW_WORKING_SET = 4.25
+
+
+def auto_kind(state_bytes: int, bytes_limit: Optional[int],
+              n_devices: int) -> str:
+    """The engine kind a request of kind ``'auto'`` runs: ``'dist-block'``
+    (its domain sharded over every local device) when one row's working
+    set on the block path does not fit one device's ``bytes_limit`` and
+    there is more than one device, else ``'block'``. ``bytes_limit``
+    None (a backend that reports no limit) never shards."""
+    if (n_devices > 1 and bytes_limit is not None
+            and BLOCK_ROW_WORKING_SET * state_bytes > bytes_limit):
+        return "dist-block"
+    return "block"
+
+
+def device_capacity() -> Tuple[Optional[int], int]:
+    """(``memory_stats()["bytes_limit"]`` of the first local device, or
+    None where the backend reports none; the local device count)."""
+    import jax
+    devs = jax.local_devices()
+    stats = devs[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return (None if limit is None else int(limit)), len(devs)
+
+
 def is_block_kind(kind: str) -> bool:
     return kind.startswith(_BLOCK_PREFIX)
 

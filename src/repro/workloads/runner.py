@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.tuning.spec import EngineSpec, is_dist_kind
+from repro.tuning.spec import EngineSpec
 from repro.workloads.base import StencilWorkload
 from repro.workloads.rules import LIFE
 
@@ -78,9 +78,8 @@ Array = jnp.ndarray
 Key = EngineSpec
 
 
-def _is_dist(kind: str) -> bool:
-    """Multi-device engine kinds (block-axis sharding over a mesh)."""
-    return is_dist_kind(kind)
+def _shape_key(states, donate: bool) -> Tuple:
+    return tuple(states.shape), jnp.dtype(states.dtype).name, bool(donate)
 
 
 @dataclasses.dataclass
@@ -107,6 +106,13 @@ class _Entry:
     #: the engine's device tables, passed to every compiled call as an
     #: argument (never closed over: jit would bake them in as constants)
     ops: object = dataclasses.field(default_factory=dict)
+    #: ``count(steps, batch)`` after each run, for engines that account
+    #: their launches on the host (None: nothing to count)
+    count: Optional[callable] = None
+    #: executables ``compile_run`` built, by (shape, dtype, donate): a
+    #: launch of that shape runs one of them, since a program compiled
+    #: ahead does not fill its jit's own cache
+    compiled: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,19 +216,16 @@ class BatchedRunner:
         # engine.tune.* outcome, per runner call; none per build)
         engine = make_engine(spec, frac=res.frac, workload=workload,
                              mesh=res.mesh, table=None)
-        if _is_dist(kind):
-            # the distributed engine owns its jit cache, its fused-launch
-            # tiling (exactly ceil(steps/k) collectives) and its exchange
-            # accounting — the runner must not wrap it in another jit, or
-            # the Python-side collective counters would only run at trace
-            # time. Its step/run handle (B, C?, nb_padded, rho, rho)
-            # natively (one batched strip all-gather per launch).
+        if hasattr(engine, "run_program"):
+            # the sharded 'dist-*' engines compile their whole run as one
+            # program of their own (shardings named, one halo exchange
+            # per launch); the runner counts each run's exchanges on
+            # the engine from (steps, k)
             return _Entry(engine,
                           lambda states, ops: engine.step_batched(states),
-                          lambda states, steps, ops: engine.run(
-                              states, int(steps)),
-                          lambda states, steps, ops: engine.run(
-                              states, int(steps), donate=True))
+                          engine.run_program(),
+                          engine.run_program(donate=True),
+                          engine.operands(), count=engine.count_run)
         fused = spec.is_block and k > 1
         stats = self.stats
         # the v5 'mxu' engine advances the whole batch through ONE kernel
@@ -360,7 +363,7 @@ class BatchedRunner:
             seeds, frac = frac, None  # init_batch(spec, seeds) form
         res = self._resolve(kind, frac, r, m, workload, None, mesh, axis)
         engine = self._get(res).engine
-        if _is_dist(res.spec.kind):
+        if hasattr(engine, "init_batch"):  # drawn in place, shard by shard
             return engine.init_batch(seeds)
         states = jnp.stack([engine.init_random(int(s)) for s in seeds])
         if res.mesh is not None:
@@ -388,10 +391,10 @@ class BatchedRunner:
         floor(steps/k) fused k-step launches plus a steps%k single-step
         remainder (``k=None``: tuning table, then the engine heuristic;
         non-block kinds step singly). ``steps`` is a dynamic fori_loop
-        bound: changing it does not retrace (the 'dist-*' kinds instead
-        tile in the engine so the collective count is exactly
-        ceil(steps/k); their remainder launch compiles once per distinct
-        steps%k, bounded by k). ``donate=True`` hands the ``states``
+        bound: changing it does not retrace (the 'dist-*' kinds run one
+        program of their own whose remainder is one launch of depth
+        steps%k, so a run makes exactly ceil(steps/k) exchanges).
+        ``donate=True`` hands the ``states``
         buffer to XLA for in-place reuse — zero-copy steady-state
         stepping; the caller must not use ``states`` afterwards.
         Spec form: ``run(spec, states, steps, donate=...)``.
@@ -406,9 +409,13 @@ class BatchedRunner:
         res = self._resolve(kind, frac, r, m, workload, k, mesh, axis)
         entry = self._get(res)
         label = res.spec.kind
-        fn = entry.batched_run_donated if donate else entry.batched_run
+        fn = entry.compiled.get(_shape_key(states, donate))
+        if fn is None:
+            fn = entry.batched_run_donated if donate else entry.batched_run
         with obs.span("runner.run", kind=label, steps=int(steps)):
             out = fn(states, jnp.asarray(steps, jnp.int32), entry.ops)
+        if entry.count is not None:
+            entry.count(int(steps), int(states.shape[0]))
         if t0 is not None:
             obs.observe("runner.run.seconds",
                         time.perf_counter() - t0, kind=label)
@@ -425,17 +432,18 @@ class BatchedRunner:
                     mesh=None):
         """Compile ``run(spec, states, ...)`` for this batch shape ahead
         of the launch, so the launch itself starts warm; returns the
-        compiled executable. None for kinds that compile inside their
-        own ``run`` (the 'dist-*' engines own their jit cache and tile
-        their launches)."""
+        compiled executable, for every kind (``states`` may be an
+        unsharded shape: the 'dist-*' programs name their own
+        shardings)."""
         res = self._resolve(spec, frac, None, 0, workload, None, mesh)
         entry = self._get(res)
-        fn = entry.batched_run_donated if donate else entry.batched_run
-        if not hasattr(fn, "lower"):
-            return None
-        return fn.lower(jax.ShapeDtypeStruct(states.shape, states.dtype),
-                        jax.ShapeDtypeStruct((), jnp.int32),
-                        entry.ops).compile()
+        key = _shape_key(states, donate)
+        if key not in entry.compiled:
+            fn = entry.batched_run_donated if donate else entry.batched_run
+            entry.compiled[key] = fn.lower(
+                jax.ShapeDtypeStruct(states.shape, states.dtype),
+                jax.ShapeDtypeStruct((), jnp.int32), entry.ops).compile()
+        return entry.compiled[key]
 
     def to_expanded(self, kind, frac=None, r: Optional[int] = None,
                     states: Optional[Array] = None, m: int = 0,

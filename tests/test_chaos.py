@@ -36,7 +36,6 @@ def _cfg(tmp_path, **kw):
     kw.setdefault("backoff_base_s", 0.01)
     kw.setdefault("backoff_cap_s", 0.05)
     kw.setdefault("hang_threshold_s", 1.0)
-    kw.setdefault("compile_grace_s", 60.0)
     kw.setdefault("ckpt_dir", str(tmp_path / "ckpts"))
     return ServiceConfig(**kw)
 

@@ -204,9 +204,10 @@ def test_exchange_bytes_model():
 
 def test_exchange_bytes_model_p2p():
     """The p2p twin: bytes_permuted matches the analytic per-neighbor
-    routing volume (ms_prev + ms_next slots per device per launch),
-    neighbor_sends counts 2*(n_shards-1) directed sends per launch, and
-    nothing is all-gathered."""
+    routing volume (ms_prev + ms_next slots per device per launch: whole
+    blocks on the XLA compute, depth-k edge strips on the kernel
+    computes), neighbor_sends counts 2*(n_shards-1) directed sends per
+    launch, and nothing is all-gathered."""
     layout = _layout()
     k = 3
     dist = make_distributed_engine(layout, workload=LIFE, compute="jnp",
@@ -219,9 +220,14 @@ def test_exchange_bytes_model_p2p():
     assert st.bytes_permuted == dist.permute_bytes(k)
     assert st.neighbor_sends == 2 * (dist.n_shards - 1)
     d = dist.decomp
+    itemsize = jnp.dtype(LIFE.dtype).itemsize
     assert dist.wire_bytes_per_device(k) == (
-        (d.ms_prev + d.ms_next) * 4 * k * layout.rho
-        * jnp.dtype(LIFE.dtype).itemsize)
+        (d.ms_prev + d.ms_next) * layout.rho ** 2 * itemsize)
+    kernel = make_distributed_engine(layout, workload=LIFE,
+                                     compute="fused", fusion_k=k,
+                                     interpret=True, exchange="p2p")
+    assert kernel.wire_bytes_per_device(k) == (
+        (d.ms_prev + d.ms_next) * 4 * k * layout.rho * itemsize)
 
 
 def test_memory_bytes():

@@ -97,7 +97,6 @@ def _serve_one(tmp_path, wl, name):
     """One request with snapshots and checkpoints; then the same rid on
     a fresh service, which completes from the final checkpoint."""
     cfg = ServiceConfig(max_batch=2, hang_threshold_s=5.0,
-                        compile_grace_s=60.0,
                         ckpt_dir=str(tmp_path / name))
 
     def req():

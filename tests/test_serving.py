@@ -23,7 +23,6 @@ def _cfg(**kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("backoff_base_s", 0.01)
     kw.setdefault("hang_threshold_s", 5.0)
-    kw.setdefault("compile_grace_s", 60.0)
     return ServiceConfig(**kw)
 
 

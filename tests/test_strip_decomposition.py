@@ -10,10 +10,11 @@ against the layout's offset_table, interior/boundary classification,
 routing buffer consistency, degenerate-mesh detection, and the wire
 accounting the scaling gate reads.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import fractals
+from repro.core import fractals, maps
 from repro.core.compact import (BlockLayout, StripDecomposition,
                                 _balanced_contiguous_partition)
 
@@ -117,20 +118,26 @@ def test_combined_table_decodes_to_neighbor_table(frac, r, m, ns):
 @pytest.mark.parametrize("frac,r,m,ns", CONFIGS,
                          ids=lambda c: getattr(c, "name", c))
 def test_interior_boundary_partition(frac, r, m, ns):
-    """interior_idx and boundary_idx partition [0, nbl): each real slot
-    appears exactly once, interior slots' table rows are fully local
-    (no combined slot past the ghost row), every boundary slot has at
-    least one remote reference; sentinel padding only."""
+    """Every boundary slot (one with a remote reference: a combined slot
+    past the ghost row) lies in its shard's head [0, first row) with a
+    prev neighbor, or in its tail [n - last row, n) with a next one
+    (``edge_rows``, the ranges the p2p boundary pass recomputes); every
+    other slot's table row is fully local; padding slots past the
+    shard's n real blocks are interior."""
     layout, d = _decomp(frac, r, m, ns)
     nbl = d.nb_local
+    real = (d.perm.reshape(ns, nbl) >= 0).sum(axis=1)
     for s in range(ns):
-        ii = [x for x in d.interior_idx[s] if x < nbl]
-        bi = [x for x in d.boundary_idx[s] if x < nbl]
-        assert sorted(ii + bi) == list(range(nbl))
-        for li in ii:
-            assert (d.table[s, li] <= nbl).all(), (s, li)
-        for li in bi:
-            assert (d.table[s, li] > nbl).any(), (s, li)
+        first, last = d.edge_rows[s]
+        n = real[s]
+        remote = (d.table[s] > nbl).any(axis=1)
+        for li in np.flatnonzero(remote):
+            head = s > 0 and li < first
+            tail = s < ns - 1 and n - last <= li < n
+            assert head or tail, (s, li, first, last, n)
+        assert not remote[n:].any()
+        assert (d.table[s][~remote] <= nbl).all()
+    assert d.edge_rows.sum() > 0
 
 
 @pytest.mark.parametrize("frac,r,m,ns", CONFIGS,
@@ -211,3 +218,33 @@ def test_wire_accounting_scales_with_shards_not_blocks():
         * layout.strip_decomposition(8).slot_bytes(2, 1)
     nb_share = layout.n_blocks // 8 * 4 * 2 * layout.rho
     assert pd[8] < nb_share, "per-device wire bytes track nb — not flat"
+
+
+@pytest.mark.parametrize("frac,r,m", [
+    (fractals.SIERPINSKI, 7, 2), (fractals.SIERPINSKI, 6, 0),
+    (fractals.SIERPINSKI, 3, 2),
+    (fractals.CARPET, 4, 1), (fractals.VICSEK, 4, 1),
+    (fractals.EMPTY_BOTTLES, 3, 1), (fractals.CHANDELIER, 3, 0)],
+    ids=lambda c: getattr(c, "name", c))
+def test_block_tables_match_the_paper_maps(frac, r, m):
+    """The host tables the decomposition is built from are the paper's
+    maps at block granularity: each block's origin is lambda of its
+    compact coordinate, and ``block_ids_at`` is nu with membership at
+    every expanded block position, two beyond each edge included."""
+    layout = BlockLayout(frac, r, m)
+    bx, by = layout.block_coords
+    ex, ey = maps.lambda_map(frac, layout.r_b, jnp.asarray(bx),
+                             jnp.asarray(by))
+    np.testing.assert_array_equal(
+        layout.block_origin_expanded,
+        np.stack([ex, ey], axis=1) * layout.rho)
+    side = frac.s ** layout.r_b
+    y, x = np.meshgrid(np.arange(-2, side + 2, dtype=np.int32),
+                       np.arange(-2, side + 2, dtype=np.int32),
+                       indexing="ij")
+    cx, cy, valid = maps.nu_with_membership(frac, layout.r_b,
+                                            jnp.asarray(x), jnp.asarray(y))
+    want = np.where(valid, cy * layout.block_dims[1] + cx, layout.ghost)
+    got = layout.block_ids_at(x, y)
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)

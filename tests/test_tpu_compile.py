@@ -13,11 +13,14 @@ these tests loads the TPU compiler, and where it cannot be described
 the tests skip.
 """
 import re
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro.core import fractals
 from repro.core.compact import BlockLayout
@@ -36,16 +39,20 @@ PDE = (fractals.CARPET, 8, 2, 1)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     # a compile that cannot be read back without a chip must not land in
     # (and warn from) the persistent cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -54,7 +61,12 @@ def _sds(sharding, shape, dtype):
 
 
 def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+    return _fits(jax.jit(fn).lower(*args).compile())
+
+
+def _fits(compiled):
+    """The compiled program's text, once its memory (per device, for a
+    sharded program) is known to fit one chip."""
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -154,6 +166,109 @@ def test_handoff_relayout_is_tile_linear(one_chip, case):
     wl, frac, r, m, dtype = HANDOFF_ROWS[case]
     row = _state(one_chip, BlockLayout(frac, r, m), wl)
     text = _compile(tile_linear, _sds(one_chip, row.shape, dtype))
+    out = re.search(r"entry_computation_layout=\{\(.*?\)->(.*?)\}",
+                    text).group(1)
+    assert re.fullmatch(r"u32\[\d+,128\]\{1,0:T\(8,128\)", out), out
+
+
+def _strip_sizes(frac, r_b, n_shards):
+    """The strip decomposition's sizes at block level ``r_b``, from the
+    fractal's block count per expanded block row (no per-block table):
+    the same balanced row partition, so ``nb_local`` and ``edge_rows``
+    are exact, and each routing list at most its shard's first or last
+    row."""
+    from repro.core.compact import _balanced_contiguous_partition
+    slots = np.zeros(frac.s, np.int64)
+    for _, y in frac.positions:
+        slots[y] += 1
+    counts = np.ones(1, np.int64)
+    for _ in range(r_b):
+        counts = np.kron(slots, counts)
+    counts = counts[counts > 0]
+    bounds = _balanced_contiguous_partition(counts, n_shards)
+    edge = np.array([(counts[a], counts[b - 1]) for a, b in bounds])
+    nbl = max(int(counts[a:b].sum()) for a, b in bounds)
+    return SimpleNamespace(
+        valid=True, nb_local=nbl, nb_padded=n_shards * nbl,
+        edge_rows=edge, ms_prev=int(edge[:, 0].max()),
+        ms_next=int(edge[:, 1].max()))
+
+
+def test_strip_sizes_bound_the_decomposition():
+    """The sizes the r=20 compile below takes agree with the real
+    decomposition where it can be built: nb_local and the edge rows
+    exact, the routing widths bounded."""
+    frac, n = fractals.SIERPINSKI, 4
+    for r in (9, 11):
+        layout = BlockLayout(frac, r, 4)
+        d = layout.strip_decomposition(n)
+        got = _strip_sizes(frac, layout.r_b, n)
+        assert got.nb_local == d.nb_local
+        np.testing.assert_array_equal(got.edge_rows, d.edge_rows)
+        assert d.ms_prev <= got.ms_prev and d.ms_next <= got.ms_next
+
+
+def test_r20_shard_pass_takes_bounded_chunks(topo):
+    """A shard of the r=20 domain (10.77 M blocks) runs each pass in at
+    most ``MAX_SHARD_CHUNKS`` chunks, each a whole multiple of
+    ``window_chunk``'s, its slots a whole number of them; a shard that
+    fits the bound keeps ``window_chunk``'s size."""
+    from repro.core import distributed
+    from repro.core.compact import window_chunk
+    frac = fractals.SIERPINSKI
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n = len(topo.devices)
+    for r, grows in ((20, True), (12, False)):
+        eng = distributed.DistributedSqueezeEngine(
+            BlockLayout(frac, r, 4), mesh, workload=LIFE, compute="jnp")
+        eng.__dict__["decomp"] = _strip_sizes(frac, r - 4, n)
+        base = window_chunk(16, 3)
+        chunk = eng._chunk(3)
+        assert chunk % base == 0 and (grows or chunk > eng.nb_local)
+        assert -(-eng.nb_local // chunk) <= distributed.MAX_SHARD_CHUNKS
+        assert (chunk > base) == grows, (r, chunk, base)
+        if grows:
+            assert eng.nb_local % chunk == 0
+            assert eng.nb_local - eng.decomp.nb_local < chunk
+
+
+def test_sharded_r20_step_program_fits(topo):
+    """The service's run program for Life at Sierpinski r=20, m=4 (11.0
+    GB of u8 block state, one domain) sharded over the four chips of a
+    v5e host, B=1, donated: it compiles for the described ``v5e:2x2``,
+    exchanges by collective permutes only, and each chip's arguments +
+    output + temporaries - aliased fit its 16 GB."""
+    from repro.core.distributed import DistributedSqueezeEngine
+    frac, r, m = fractals.SIERPINSKI, 20, 4
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    eng = DistributedSqueezeEngine(BlockLayout(frac, r, m), mesh,
+                                   workload=LIFE, compute="jnp")
+    d = _strip_sizes(frac, r - m, len(topo.devices))
+    eng.__dict__["decomp"] = d   # the sizes, without the 43 M-block build
+    assert eng.exchange_mode == "p2p" and eng.effective_fusion_k == 3
+    ns = len(topo.devices)
+
+    def arg(shape, spec, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    row = P("data", None)
+    ops = (arg((eng.nb_padded + ns, 9), row), arg((ns, d.ms_prev), row),
+           arg((ns, d.ms_next), row), arg((ns, 1), row))
+    state = arg((1, eng.nb_padded, 16, 16), P(None, "data"), jnp.uint8)
+    compiled = eng.run_program(donate=True).lower(
+        state, arg((), P()), ops).compile()
+    text = _fits(compiled)
+    assert "collective-permute" in text and "all-gather" not in text
+
+
+def test_r20_shard_handoff_piece_fits(one_chip):
+    """A shard of the r=20 domain goes to the host in pieces of at most
+    ``PIECE_BYTES``: the relayout of one piece compiles, fits beside the
+    state, and is tile-linear."""
+    from repro.serving.handoff import PIECE_BYTES, tile_linear
+    n = PIECE_BYTES // (16 * 16)
+    text = _compile(tile_linear, _sds(one_chip, (n, 16, 16), jnp.uint8))
     out = re.search(r"entry_computation_layout=\{\(.*?\)->(.*?)\}",
                     text).group(1)
     assert re.fullmatch(r"u32\[\d+,128\]\{1,0:T\(8,128\)", out), out
