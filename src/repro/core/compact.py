@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import maps
 from repro.core.fractals import NBBFractal
 #: Moore neighborhood directions (dx, dy), y growing downward — defined in
@@ -84,6 +85,209 @@ def expanded_to_compact(frac: NBBFractal, r: int, state_e: Array) -> Array:
     cx, cy = compact_meshgrid(frac, r)
     ex, ey = maps.lambda_map(frac, r, cx, cy)
     return state_e[..., ey, ex]
+
+
+#: groups of ``WordView.group`` x 128 blocks one program of the packing
+#: kernel transposes (about 1 MiB in and out at rho=16, uint8)
+PACK_GROUPS = 8
+
+#: gathered rows one program of the unpacking kernel transposes (512 KiB
+#: of words in, at rho=16, uint8)
+UNPACK_BLOCKS = 1024
+
+
+@dataclasses.dataclass(frozen=True)
+class WordView:
+    """A lane-dense block state (..., n, rho*rho) of sub-word cells seen
+    as 32-bit words, ``group`` blocks to a row of whole 128-lane tiles:
+    (..., ceil(n/span)*128, group*words), ``span`` = 128*group and
+    ``words`` = rho*rho*itemsize/4. Block ``i`` is row ``(i // span) *
+    128 + i % 128``, lanes ``(i % span // 128) * words`` on; its word
+    ``k`` holds cells ``k*per .. k*per+per-1`` (``per`` cells to a
+    word, packed as a bitcast packs them).
+
+    A TPU packs a sub-word array's rows into the bytes of its words, so
+    a gather of block rows whose leading dims do not fill a word reads
+    and writes parts of words; gathered from this view, every block is
+    whole words. The view holds the state's bytes (its last span
+    padded). On a TPU one Pallas kernel writes it from the state's own
+    layout (blocks on the lanes, a block's cells packed down the
+    sublanes: a bitcast and a transpose of each span), and another
+    turns gathered rows back into cells in that layout."""
+
+    group: int
+    dtype: np.dtype
+
+    @classmethod
+    def of(cls, lead, dtype, rho: int) -> Optional["WordView"]:
+        """The view for a state with leading (batch, channel) dims
+        ``lead``, or None where its block rows already fill whole words
+        (the leading dims of one cell span 4 bytes or more) or a block
+        is no whole number of 8-word sublane tiles, or of 128-lane
+        rows, or does not divide one."""
+        item = np.dtype(dtype).itemsize
+        if item * math.prod(lead) >= 4 or rho * rho * item % 32:
+            return None
+        words = rho * rho * item // 4
+        if 128 % words == 0:
+            return cls(128 // words, np.dtype(dtype))
+        return cls(1, np.dtype(dtype)) if words % 128 == 0 else None
+
+    @property
+    def span(self) -> int:
+        return 128 * self.group
+
+    @property
+    def _uint(self):
+        """The unsigned dtype of one cell's bits (bool cells are 0/1)."""
+        return np.dtype(f"uint{8 * np.dtype(self.dtype).itemsize}")
+
+    def pack(self, flat: Array) -> Array:
+        """The view of ``flat`` (..., n, rho*rho): the Pallas kernel
+        where the program is lowered for a TPU, else the same words from
+        ``pack_reference``."""
+        if self.dtype == np.bool_:
+            bits = flat.astype(self._uint)
+        else:
+            bits = jax.lax.bitcast_convert_type(flat, self._uint)
+        return jax.lax.platform_dependent(
+            bits, tpu=functools.partial(self.pack_kernel, interpret=False),
+            default=self.pack_reference)
+
+    def pack_reference(self, bits: Array) -> Array:
+        """``pack`` of unsigned cell bits by jnp reshapes."""
+        lead, (n, cells) = bits.shape[:-2], bits.shape[-2:]
+        per = 4 // bits.dtype.itemsize
+        words = jax.lax.bitcast_convert_type(
+            bits.reshape(lead + (n, cells // per, per)), jnp.uint32)
+        pad = [(0, 0)] * words.ndim
+        pad[-2] = (0, -n % self.span)
+        words = jnp.pad(words, pad).reshape(
+            lead + (-1, self.group, 128, cells // per))
+        nd = len(lead)
+        words = jnp.transpose(words, tuple(range(nd))
+                              + (nd, nd + 2, nd + 1, nd + 3))
+        return words.reshape(lead + (-1, self.group * cells // per))
+
+    def pack_kernel(self, bits: Array, interpret: bool) -> Array:
+        """``pack`` of unsigned cell bits (..., n, cells) by a Pallas
+        kernel over their transpose (..., cells, n), which is the
+        state's own TPU layout: each program bitcasts ``PACK_GROUPS``
+        spans of blocks to words and transposes them."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        lead, (n, cells) = bits.shape[:-2], bits.shape[-2:]
+        src = jnp.swapaxes(bits.reshape((-1, n, cells)), -1, -2)
+        width = self.group * cells * bits.dtype.itemsize // 4
+        span = self.span
+        spans = -(-n // span)
+        step = min(PACK_GROUPS, spans)
+
+        def kernel(x_ref, o_ref):
+            for t in range(step):
+                w = pltpu.bitcast(x_ref[:, t * span:(t + 1) * span],
+                                  jnp.uint32)
+                w = jnp.concatenate([w[:, j * 128:(j + 1) * 128]
+                                     for j in range(self.group)], axis=0)
+                o_ref[t * 128:(t + 1) * 128, :] = w.T
+
+        out = pl.pallas_call(
+            kernel,
+            grid=(src.shape[0], -(-spans // step)),
+            in_specs=[pl.BlockSpec((None, cells, step * span),
+                                   lambda b, i: (b, 0, i))],
+            out_specs=pl.BlockSpec((None, step * 128, width),
+                                   lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(
+                (src.shape[0], spans * 128, width), jnp.uint32),
+            interpret=interpret)(src)
+        return out.reshape(lead + out.shape[1:])
+
+    def gather(self, src: Array, idx: Array, n_blocks: int, rho: int,
+               extra: Optional[Array] = None, m: int = 0) -> Array:
+        """``BlockLayout.block_rows`` through the view: blocks ``idx``
+        (c,) of ``src`` (the view of ``n_blocks`` blocks), ids
+        ``n_blocks + i`` naming block ``i`` of ``extra`` (the view of
+        ``m``), ids past both zero. Whole rows of words are gathered;
+        ``unpack`` picks each block's lanes."""
+        rows, sub = self._rows(src, jnp.minimum(idx, n_blocks - 1))
+        near = idx < n_blocks
+        code = jnp.where(near, sub, -1)
+        if extra is not None:
+            far, far_sub = self._rows(extra,
+                                      jnp.clip(idx - n_blocks, 0, m - 1))
+            rows = jnp.where(near[:, None], rows, far)
+            code = jnp.where(near, code, jnp.where(idx < n_blocks + m,
+                                                   far_sub, -1))
+        return self.unpack(rows, code, rho)
+
+    def _rows(self, src: Array, ids: Array) -> Tuple[Array, Array]:
+        """The rows of ``src`` holding blocks ``ids``, and which of a
+        row's ``group`` blocks each is."""
+        span = self.span
+        return (jnp.take(src, ids // span * 128 + ids % 128, axis=-2,
+                         mode="clip"), ids % span // 128)
+
+    def unpack(self, rows: Array, code: Array, rho: int) -> Array:
+        """(..., c, group*words) gathered rows -> (..., c, rho, rho)
+        cells of block ``code`` (c,) of each row, zero where ``code`` is
+        -1: on a TPU a Pallas kernel that writes them blocks on the
+        lanes (the layout the window assembly reads), else
+        ``unpack_reference``."""
+        bits = jax.lax.platform_dependent(
+            rows, code,
+            tpu=functools.partial(self.unpack_kernel, interpret=False),
+            default=self.unpack_reference)
+        cells = bits.reshape(bits.shape[:-1] + (rho, rho))
+        if self.dtype == np.bool_:
+            return cells.astype(self.dtype)
+        return jax.lax.bitcast_convert_type(cells, self.dtype)
+
+    def unpack_reference(self, rows: Array, code: Array) -> Array:
+        """``unpack``'s unsigned cell bits (..., c, cells) by jnp."""
+        words = rows.shape[-1] // self.group
+        pick = jnp.zeros(rows.shape[:-1] + (words,), jnp.uint32)
+        for j in range(self.group):
+            pick = jnp.where((code == j)[:, None],
+                             rows[..., j * words:(j + 1) * words], pick)
+        bits = jax.lax.bitcast_convert_type(pick, self._uint)
+        return bits.reshape(rows.shape[:-1] + (-1,))
+
+    def unpack_kernel(self, rows: Array, code: Array,
+                      interpret: bool) -> Array:
+        """``unpack``'s unsigned cell bits by a Pallas kernel: each
+        program transposes ``UNPACK_BLOCKS`` rows, keeps each block's
+        lanes by its code and bitcasts the words to cells, written
+        (..., cells, c) and returned as its (..., c, cells) view."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        lead, (c, width) = rows.shape[:-2], rows.shape[-2:]
+        src = rows.reshape((-1, c, width))
+        words = width // self.group
+        cells = words * 4 // self._uint.itemsize
+        step = min(UNPACK_BLOCKS, -(-c // 128) * 128)
+
+        def kernel(code_ref, x_ref, o_ref):
+            t, sel = x_ref[...].T, code_ref[...]
+            out = jnp.zeros((words, step), jnp.uint32)
+            for j in range(self.group):
+                out = jnp.where(sel == j, t[j * words:(j + 1) * words], out)
+            o_ref[...] = pltpu.bitcast(out, self._uint)
+
+        out = pl.pallas_call(
+            kernel,
+            grid=(src.shape[0], -(-c // step)),
+            in_specs=[pl.BlockSpec((1, step), lambda b, i: (0, i)),
+                      pl.BlockSpec((None, step, width),
+                                   lambda b, i: (b, i, 0))],
+            out_specs=pl.BlockSpec((None, cells, step),
+                                   lambda b, i: (b, 0, i)),
+            out_shape=jax.ShapeDtypeStruct((src.shape[0], cells, c),
+                                           self._uint),
+            interpret=interpret)(code.reshape(1, c), src)
+        return jnp.swapaxes(out, -1, -2).reshape(lead + (c, cells))
 
 
 # ======================================================================
@@ -547,14 +751,19 @@ class BlockLayout:
 
     @staticmethod
     def block_rows(flat: Array, idx: Array, n_blocks: int,
-                   rho: int, extra: Optional[Array] = None) -> Array:
+                   rho: int, extra: Optional[Array] = None,
+                   m: int = 0, view: Optional[WordView] = None) -> Array:
         """Gather blocks ``idx`` (n,) from ``flat`` (..., n_blocks,
         rho*rho), the lane-dense view of a block state: ids past the
         layout (the ghost sentinel, padding rows) read as zero blocks.
         With ``extra`` (..., m, rho*rho), ids ``n_blocks + i`` name its
         row ``i``, so ``flat`` and ``extra`` read as one buffer without
-        being concatenated, and ids past both read as zero. Returns (...,
+        being concatenated, and ids past both read as zero. With
+        ``view``, ``flat`` and ``extra`` are its packed words and ``m``
+        is ``extra``'s block count (``WordView.gather``). Returns (...,
         n, rho, rho)."""
+        if view is not None:
+            return view.gather(flat, idx, n_blocks, rho, extra, m)
         rows = jnp.take(flat, jnp.minimum(idx, n_blocks - 1), axis=-2)
         if extra is None:
             rows = rows * (idx < n_blocks)[:, None].astype(rows.dtype)
@@ -566,9 +775,25 @@ class BlockLayout:
             rows = rows * (idx < n_blocks + m)[:, None].astype(rows.dtype)
         return rows.reshape(rows.shape[:-1] + (rho, rho))
 
+    def chunk_source(self, state: Array
+                     ) -> Tuple[Optional[WordView], Array]:
+        """What ``map_chunks`` may gather the blocks of ``state`` (...,
+        n, rho, rho) from: its ``WordView`` and packed words where it has
+        one (sub-word cells whose leading dims fill no word), else None
+        and its lane-dense (..., n, rho*rho) rows. Only a state that
+        shows all its leading dims can say whether its rows fill words:
+        a per-row state under a vmap cannot. Passes over one state may
+        share it."""
+        lead, rho = state.shape[:-3], self.rho
+        flat = state.reshape(lead + (state.shape[-3], rho * rho))
+        view = WordView.of(lead, state.dtype, rho)
+        return view, flat if view is None else view.pack(flat)
+
     def map_chunks(self, state: Array, table: Array, chunk: int, fn,
                    ids: Optional[Array] = None,
-                   extra: Optional[Array] = None):
+                   extra: Optional[Array] = None,
+                   source: Optional[Tuple[Optional[WordView],
+                                          Array]] = None):
         """Apply a halo-reading block update chunk by chunk.
 
         ``state`` (..., n, rho, rho), ``n`` the layout's blocks or a
@@ -585,19 +810,32 @@ class BlockLayout:
         rho*rho) view, so every temporary with small trailing dims (halo
         strips, windows, which a TPU pads to (8, 128) tiles) is
         chunk-sized: the working set stays bounded however many blocks
-        the layout has. One chunk when ``chunk >= R``."""
+        the layout has. One chunk when ``chunk >= R``.
+
+        Blocks are gathered from the state's rows, or from ``source``,
+        ``chunk_source(state)``, where the caller gives it: whole 32-bit
+        words where the state has a ``WordView``.
+        ``engine.gather_view{view}`` counts the traces that take each
+        view."""
         nb, rho = state.shape[-3], self.rho
         lead = state.shape[:-3]
-        flat = state.reshape(lead + (nb, rho * rho))
         n_rows = table.shape[0]
+        view, flat = ((None, state.reshape(lead + (nb, rho * rho)))
+                      if source is None else source)
+        obs.inc("engine.gather_view", view="rows" if view is None
+                else "words")
+        m = 0 if extra is None else extra.shape[-2]
+        if view is not None and extra is not None:
+            extra = view.pack(extra)
+
+        def rows_of(idx, far=None):
+            return self.block_rows(flat, idx, nb, rho, far, m, view)
 
         def nbr_of(rows):
-            return lambda j: self.block_rows(flat, rows[:, j], nb, rho,
-                                             extra)
+            return lambda j: rows_of(rows[:, j], extra)
 
         if chunk >= n_rows:
-            center = state if ids is None else self.block_rows(
-                flat, ids, nb, rho)
+            center = state if ids is None else rows_of(ids)
             return fn(center, nbr_of(table), table)
         n_chunks = -(-n_rows // chunk)
         pad = n_chunks * chunk - n_rows
@@ -614,8 +852,7 @@ class BlockLayout:
                 cid = base + jnp.arange(chunk, dtype=table.dtype)
             else:
                 cid = jax.lax.dynamic_slice_in_dim(ids, base, chunk, 0)
-            res = fn(self.block_rows(flat, cid, nb, rho), nbr_of(rows),
-                     rows)
+            res = fn(rows_of(cid), nbr_of(rows), rows)
             return jax.lax.dynamic_update_slice_in_dim(
                 out, res.reshape(lead + (chunk, rho * rho)), base,
                 axis=out.ndim - 2)

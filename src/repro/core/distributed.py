@@ -831,7 +831,10 @@ class DistributedSqueezeEngine:
         zero = jnp.zeros((b, nc, 1, rho * rho), state_local.dtype)
         update = self._block_update(k, nbl)
         chunk = self._chunk(k)
-        out = layout.map_chunks(state_local, table[:nbl], chunk, update)
+        # the three passes gather from one source (one packing a launch)
+        source = layout.chunk_source(state_local)
+        out = layout.map_chunks(state_local, table[:nbl], chunk, update,
+                                source=source)
         extra = jnp.concatenate(
             [zero, recv_prev.reshape((b, nc) + recv_prev.shape[1:]),
              recv_next.reshape((b, nc) + recv_next.shape[1:])], axis=2)
@@ -839,7 +842,7 @@ class DistributedSqueezeEngine:
             ids = start + jnp.arange(width, dtype=table.dtype)
             rows = jax.lax.dynamic_slice_in_dim(table, start, width, 0)
             part = layout.map_chunks(state_local, rows, chunk, update,
-                                     ids=ids, extra=extra)
+                                     ids=ids, extra=extra, source=source)
             out = jax.lax.dynamic_update_slice_in_dim(out, part, start, 2)
         return out
 

@@ -12,6 +12,8 @@ The topology is described inside a fixture: only the worker that runs
 these tests loads the TPU compiler, and where it cannot be described
 the tests skip.
 """
+import json
+import os
 import re
 from types import SimpleNamespace
 
@@ -147,6 +149,50 @@ def test_block_step_r17_batch_fits(one_chip):
     assert "tpu_custom_call" not in text
 
 
+#: the one-chip benchmark cells' step programs: (workload, fractal, r, m,
+#: traffic mix); the runner maps the block engine over each batch, whose
+#: per-row states never ask for the word view
+ROW_GATHER_CELLS = {
+    "life-r17-batch4": (LIFE, fractals.SIERPINSKI, 17, 4,
+                        "closed-c4-r17-s64"),
+    "grayscott-carpet-r9-batch2": (GRAY_SCOTT, fractals.CARPET, 9, 2,
+                                   "closed-c2-r9-s32")}
+
+
+def _warmed_batches():
+    """(cell, batch rows) for every batch size the benchmark warms in
+    each one-chip cell (``chipbench.loadgen.warm_sizes``)."""
+    from chipbench import loadgen
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = []
+    for cell, (*_, mix) in ROW_GATHER_CELLS.items():
+        with open(os.path.join(root, "chipbench", "traffic",
+                               mix + ".json")) as f:
+            traffic = json.load(f)
+        out += [(cell, b) for b in loadgen.warm_sizes(
+            traffic, int(traffic["clients"]))]
+    return out
+
+
+@pytest.mark.parametrize("cell,b", _warmed_batches())
+def test_one_chip_cell_steps_gather_rows(one_chip, cell, b):
+    """The runner's run program for each batch a one-chip cell warms
+    keeps the row gather: no gather of 32-bit words."""
+    from repro.core.stencil import SqueezeBlockEngine
+    wl, frac, r, m, _ = ROW_GATHER_CELLS[cell]
+    eng = SqueezeBlockEngine(BlockLayout(frac, r, m), wl)
+    k = eng.effective_fusion_k
+
+    def run(states, table):
+        body = jax.vmap(lambda s: eng.step_k(s, k, {"table": table}))
+        return jax.lax.fori_loop(0, 2, lambda _, s: body(s), states)
+
+    text = _compile(run, _state(one_chip, eng.layout, wl, b),
+                    _table(one_chip, eng.layout))
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"= u32\[[\d,]+\]\S* gather\(", text)
+
+
 #: one row of each benchmark cell's shape: Life r=17 (u8, block axis on
 #: the lanes, four cells to a word), and Gray-Scott's two channels at
 #: r=9 on the carpet in bfloat16 (its f32 rows take the plain copy; 9
@@ -232,6 +278,11 @@ def test_r20_shard_pass_takes_bounded_chunks(topo):
             assert eng.nb_local - eng.decomp.nb_local < chunk
 
 
+#: arguments + temporaries a chip of the r=20 run program took while its
+#: chunks gathered sub-word block rows (described v5e:2x2, jax 0.9.0)
+R20_ROW_GATHER_BYTES = 3_467_365_376 + 5_664_686_592
+
+
 def test_sharded_r20_step_program_fits(topo):
     """The service's run program for Life at Sierpinski r=20, m=4 (11.0
     GB of u8 block state, one domain) sharded over the four chips of a
@@ -260,6 +311,15 @@ def test_sharded_r20_step_program_fits(topo):
         state, arg((), P()), ops).compile()
     text = _fits(compiled)
     assert "collective-permute" in text and "all-gather" not in text
+    # the chunk gathers read whole 32-bit words (two blocks to a row of
+    # the word view), none a quarter of each word
+    chunk = eng._chunk(3)
+    gathers = re.findall(r"= (\w+)\[(\d+),(\d+)\]\S* gather\(", text)
+    assert ("u8", str(chunk), "256") not in gathers
+    assert gathers.count(("u32", str(chunk), "128")) >= 9
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used <= 1.01 * R20_ROW_GATHER_BYTES, used
 
 
 def test_r20_shard_handoff_piece_fits(one_chip):
